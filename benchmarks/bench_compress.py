@@ -19,11 +19,11 @@ _SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.launch.mesh import auto_mesh
     from repro.optim.compress import compressed_mean
     from repro.analysis import hlo as H
 
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = auto_mesh((16,), ("data",))
     N = 1 << 22          # 4M f32 grads per device (16 MB)
 
     def ring(x):
@@ -36,8 +36,8 @@ _SCRIPT = textwrap.dedent("""
     xs = jax.ShapeDtypeStruct((16, N), jnp.float32)
     out = {}
     for name, fn in (("int8_ring", ring), ("f32_allreduce", psum_mean)):
-        f = jax.jit(shard_map(fn, mesh=mesh, in_specs=P("data"),
-                              out_specs=P("data"), check_rep=False))
+        f = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"), check_vma=False))
         txt = f.lower(xs).compile().as_text()
         coll = H.collect(txt, 16)
         out[name] = coll.total()
@@ -48,9 +48,12 @@ _SCRIPT = textwrap.dedent("""
 
 
 def run(verbose=True):
+    # the child lowers for 16 virtual CPU devices; pinned to the CPU
+    # backend so it never reaches for an accelerator this process may hold
     r = subprocess.run([sys.executable, "-c", _SCRIPT],
                        capture_output=True, text=True, timeout=900,
-                       cwd=os.path.dirname(os.path.dirname(__file__)))
+                       cwd=os.path.dirname(os.path.dirname(__file__)),
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
     if verbose:
         print("# int8 ring reduce-scatter+all-gather vs f32 all-reduce "
               "(16-way, 16MB grads)")

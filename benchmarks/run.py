@@ -61,6 +61,8 @@ def _invoke(fn, name: str, json_dir: str | None, smoke: bool = False):
 
 
 def main(argv=None) -> None:
+    from repro import compile_cache
+    compile_cache.enable()
     argv = sys.argv[1:] if argv is None else argv
     json_dir = None
     smoke = "--smoke" in argv

@@ -22,6 +22,7 @@ import jax
 
 from repro.configs.base import AttnCfg, ModelConfig, ShapeCfg
 from repro.core import planner
+from repro.launch.mesh import auto_mesh
 from repro.runtime import TrainLoopConfig, train_loop
 from repro.runtime.elastic import rescale
 
@@ -40,7 +41,7 @@ def main():
     # Phase 1: full slice (8 chips), planner-chosen layout
     p8 = planner.plan(cfg, shape, chips=8)
     ex8 = planner.to_execution(p8, cfg=cfg, chips=8)
-    mesh8 = jax.make_mesh(ex8.mesh_shape, ex8.mesh_axes)
+    mesh8 = auto_mesh(ex8.mesh_shape, ex8.mesh_axes)
     print(f"phase 1: mesh {ex8.mesh_shape}  "
           f"(planned {p8.tokens_per_s:,.0f} tok/s)")
     s1 = train_loop(cfg, TrainLoopConfig(
@@ -68,4 +69,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     main()

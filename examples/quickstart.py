@@ -67,5 +67,7 @@ def part2_lm():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     part1_jpeg()
     part2_lm()

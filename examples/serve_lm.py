@@ -111,6 +111,8 @@ def main(pipeline: bool = False, trace_path: str | None = None,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+    compile_cache.enable()
     args = sys.argv[1:]
     trace = args[args.index("--trace") + 1] if "--trace" in args else None
     main(pipeline="--pipeline" in args, trace_path=trace,

@@ -147,6 +147,8 @@ def restore_checkpoint(ckpt_dir: str | os.PathLike, like, *, step: int | None = 
         want_shape = tuple(leaf.shape)
         if tuple(arr.shape) != want_shape:
             raise ValueError(f"{k}: checkpoint shape {arr.shape} != {want_shape}")
+        if arr.dtype.kind == "V":          # npz keeps bfloat16 as raw bytes
+            arr = arr.view(leaf.dtype)
         leaves.append(arr.astype(leaf.dtype) if hasattr(leaf, "dtype") else arr)
     tree = jax.tree_util.tree_unflatten(treedef, leaves)
     if shardings is not None:

@@ -734,6 +734,7 @@ def verify_decode_plan(pipe, *, n_groups: int, capacity_blocks: int = 2,
     cache-donation aval contract for every (batch, bucket, cap) group
     shape this serve will run.  Device-free: FIFO construction and
     `jax.eval_shape` only."""
+    import jax
     from ..models import lm
     names = list(pipe.stage_names)
     S = len(names)
@@ -766,7 +767,9 @@ def verify_decode_plan(pipe, *, n_groups: int, capacity_blocks: int = 2,
                         if desc.span is not None})
         by_desc = {desc.span: desc.name for desc in pipe.stage_descs}
         for span in spans:
-            stacked = lm.slice_periods(pipe._init_params["layers"], *span)
+            stacked = jax.eval_shape(
+                lambda layers: lm.slice_periods(layers, *span),
+                pipe._init_params["layers"])
             for (batch, bucket, cap) in sorted(set(group_shapes)):
                 verify_decode_cache_contract(
                     pipe.cfg, stacked, batch=batch, prompt=bucket,

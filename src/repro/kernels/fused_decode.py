@@ -55,8 +55,8 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 def _rope_tables(pos, d2, theta):
     """cos/sin rows (1, d2) for one absolute position (f32)."""
-    # mirrors models.common.rope's frequency layout; 2D iota for TPU
-    exp = jax.lax.broadcasted_iota(jnp.float32, (1, d2), 1) / d2
+    # mirrors models.common.rope's frequency layout; 2D int iota for TPU
+    exp = jax.lax.broadcasted_iota(jnp.int32, (1, d2), 1).astype(jnp.float32) / d2
     freq = theta ** (-exp)
     ang = pos.astype(jnp.float32) * freq
     return jnp.cos(ang), jnp.sin(ang)
@@ -98,7 +98,7 @@ def _fused_kernel(pos_ref, x_ref, kc_ref, vc_ref, norm_ref, wq_ref, wk_ref,
     d2 = head_dim // 2
     pos = pos_ref[0]
 
-    x = x_ref[...].astype(f32)                     # (1, D)
+    x = x_ref[0].astype(f32)                       # (1, D)
     w = norm_ref[...].astype(f32)                  # (1, D)
     rms = jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     h = x * rms * w                                # (1, D)
@@ -146,12 +146,18 @@ def _fused_kernel(pos_ref, x_ref, kc_ref, vc_ref, norm_ref, wq_ref, wk_ref,
     orow = jax.lax.dot_general(
         o.reshape(1, n_heads * head_dim), wo_ref[...].astype(f32),
         (((1,), (0,)), ((), ())))
-    o_ref[...] = (x + orow).astype(o_ref.dtype)
+    o_ref[0] = (x + orow).astype(o_ref.dtype)
     kn_ref[0] = k.astype(kn_ref.dtype)
     vn_ref[0] = v.astype(vn_ref.dtype)
 
 
 def _fits_vmem(d_model, n_heads, kv_heads, head_dim, cap) -> bool:
+    """Whether the single fused kernel can take this sublayer: its working
+    set fits the VMEM budget, and its head width is whole 128-lane rows
+    (the kernel splits each projection row into per-head rows, a reshape
+    Mosaic lowers only for lane-aligned head widths)."""
+    if head_dim % 128:
+        return False
     qkvo = d_model * (2 * n_heads + 2 * kv_heads) * head_dim
     cache = 2 * cap * kv_heads * head_dim
     act = 4 * d_model + 2 * n_heads * head_dim + cap * max(8, n_heads)
@@ -182,7 +188,9 @@ def _fused_pallas_step(x2, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, _p: (b, 0)),
+            # rows ride as (B, 1, D): a (1, D) block of a (B, D) array is
+            # not a whole TPU tile, a (1, 1, D) block of (B, 1, D) is
+            pl.BlockSpec((1, 1, D), lambda b, _p: (b, 0, 0)),
             pl.BlockSpec((1, cap, kv_heads, head_dim),
                          lambda b, _p: (b, 0, 0, 0)),
             pl.BlockSpec((1, cap, kv_heads, head_dim),
@@ -195,7 +203,7 @@ def _fused_pallas_step(x2, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
             *bspecs,
         ],
         out_specs=[
-            pl.BlockSpec((1, D), lambda b, _p: (b, 0)),
+            pl.BlockSpec((1, 1, D), lambda b, _p: (b, 0, 0)),
             pl.BlockSpec((1, kv_heads, head_dim), lambda b, _p: (b, 0, 0)),
             pl.BlockSpec((1, kv_heads, head_dim), lambda b, _p: (b, 0, 0)),
         ],
@@ -204,19 +212,19 @@ def _fused_pallas_step(x2, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
     out, k_new, v_new = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, D), x2.dtype),
+            jax.ShapeDtypeStruct((B, 1, D), x2.dtype),
             jax.ShapeDtypeStruct((B, kv_heads, head_dim), k_cache.dtype),
             jax.ShapeDtypeStruct((B, kv_heads, head_dim), v_cache.dtype),
         ],
         interpret=interpret,
-    )(posv, x2, k_cache, v_cache, norm.reshape(1, D), wq, wk, wv, wo,
-      *biases)
+    )(posv, x2[:, None], k_cache, v_cache, norm.reshape(1, D), wq, wk, wv,
+      wo, *biases)
     slot = jnp.mod(pos, cap)
     k_cache = jax.lax.dynamic_update_slice(
         k_cache, k_new[:, None], (0, slot, 0, 0))
     v_cache = jax.lax.dynamic_update_slice(
         v_cache, v_new[:, None], (0, slot, 0, 0))
-    return out[:, None], k_cache, v_cache
+    return out, k_cache, v_cache
 
 
 def _composed_step(x, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
@@ -255,6 +263,16 @@ def _composed_step(x, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
     return out, k_cache, v_cache
 
 
+def step_path(mode, d_model, n_heads, kv_heads, head_dim, cap) -> str:
+    """The body `attn_decode_step` runs for these shapes: ``"fused"`` (the
+    single Pallas kernel) or ``"composed"`` (`_composed_step`, whose
+    attention is the `decode_attention` kernel under pallas/interpret)."""
+    if mode in ("pallas", "interpret") and _fits_vmem(
+            d_model, n_heads, kv_heads, head_dim, cap):
+        return "fused"
+    return "composed"
+
+
 def attn_decode_step(x, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
                      bq=None, bk=None, bv=None, n_heads, head_dim,
                      eps=1e-5, rope_theta=10_000.0, mode="fused",
@@ -271,8 +289,7 @@ def attn_decode_step(x, k_cache, v_cache, pos, *, norm, wq, wk, wv, wo,
     B, _, D = x.shape
     cap, kv_heads = k_cache.shape[1], k_cache.shape[2]
     scale = head_dim ** -0.5
-    if mode in ("pallas", "interpret") and _fits_vmem(
-            D, n_heads, kv_heads, head_dim, cap):
+    if step_path(mode, D, n_heads, kv_heads, head_dim, cap) == "fused":
         return _fused_pallas_step(
             x[:, 0], k_cache, v_cache, pos, norm=norm, wq=wq, wk=wk, wv=wv,
             wo=wo, bq=bq, bk=bk, bv=bv, n_heads=n_heads, head_dim=head_dim,

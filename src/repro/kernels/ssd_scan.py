@@ -26,37 +26,48 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, s_out_ref,
                 s_ref, *, chunk: int, num_chunks: int):
+    h = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    a = a_ref[0]                                 # ()       decay rate (this head)
+    a = a_ref[h]                                 # ()  decay rate (SMEM)
     x = x_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (Q,)
+    dt = dt_ref[0, pl.ds(h, 1), :].astype(jnp.float32)   # (1, Q) this head
     bm = b_ref[0].astype(jnp.float32)            # (Q, N)
     cm = c_ref[0].astype(jnp.float32)            # (Q, N)
 
-    la = dt * a                                  # per-step log decay (Q,)
-    cs = jnp.cumsum(la)                          # inclusive cumsum (Q,)
-    # intra-chunk quadratic form
-    seg = cs[:, None] - cs[None, :]              # (Qi, Qj)
+    # Every vector stays 2-D (rows x lanes), as Mosaic lays them out: the
+    # inclusive cumsum of the per-step log decay is a masked reduction
+    # over a (Q, Q) tile, taken once along lanes (a column) and once
+    # along sublanes (a row); the column of dt is the diagonal's.
     iota_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(iota_i >= iota_j, jnp.exp(seg), 0.0)
+    lower = iota_i >= iota_j
+    la = dt * a                                            # (1, Q)
+    dt_col = jnp.sum(jnp.where(iota_i == iota_j, dt, 0.0), axis=1,
+                     keepdims=True)                        # (Q, 1)
+    cs_col = jnp.sum(jnp.where(lower, la, 0.0), axis=1,
+                     keepdims=True)                        # (Q, 1)
+    cs_row = jnp.sum(jnp.where(iota_i <= iota_j, dt_col * a, 0.0), axis=0,
+                     keepdims=True)                        # (1, Q)
+    # intra-chunk quadratic form
+    seg = cs_col - cs_row                                  # (Qi, Qj)
+    decay = jnp.where(lower, jnp.exp(seg), 0.0)
     cb = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())))   # (Qi, Qj)
-    w = cb * decay * dt[None, :]
+    w = cb * decay * dt
     y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())))      # (Qi, P)
     # inter-chunk contribution from the carried state
     s = s_ref[...]                                               # (P, N)
-    y += jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cs_col) * jax.lax.dot_general(
         cm, s, (((1,), (1,)), ((), ())))                         # (Q, P)
     y_ref[0, 0] = y.astype(y_ref.dtype)
     # state update
-    tot = cs[-1]
-    rem = jnp.exp(tot - cs) * dt                                 # (Q,)
-    dbx = jax.lax.dot_general(x, bm * rem[:, None],
+    tot = cs_row[:, chunk - 1:]                                  # (1, 1)
+    rem = jnp.exp(tot - cs_col) * dt_col                         # (Q, 1)
+    dbx = jax.lax.dot_general(x, bm * rem,
                               (((0,), (0,)), ((), ())))          # (P, N)
     s_ref[...] = s * jnp.exp(tot) + dbx
 
@@ -89,9 +100,11 @@ def ssd_scan(x, dt, a, b, c, *, chunk: int = 128, interpret: bool = False):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, h, ci: (h,)),                    # a
+            pl.BlockSpec(memory_space=pltpu.SMEM),                         # a
             pl.BlockSpec((1, 1, chunk, P), lambda bi, h, ci: (bi, h, ci, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bi, h, ci: (bi, h, ci)),    # dt
+            # dt: every head's row of the chunk (a (H, Q) tile); the
+            # kernel picks its head's row — a (1, Q) block is no tile
+            pl.BlockSpec((1, H, chunk), lambda bi, h, ci: (bi, 0, ci)),
             pl.BlockSpec((1, chunk, N), lambda bi, h, ci: (bi, ci, 0)),    # b
             pl.BlockSpec((1, chunk, N), lambda bi, h, ci: (bi, ci, 0)),    # c
         ],

@@ -8,6 +8,17 @@ from __future__ import annotations
 import jax
 
 
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    JAX 0.9 makes ``Explicit`` the default axis type, under which gathers
+    such as ``jnp.take`` on a sharded operand refuse to pick an output
+    sharding.  The model code relies on sharding propagation (and
+    ``sharding_ctx`` constraints), which is what ``Auto`` axes give."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False, tp: int | None = None,
                          rep: int | None = None):
     """16x16 single pod (256 chips) or 2x16x16 two-pod (512 chips) mesh.
@@ -29,10 +40,10 @@ def make_production_mesh(*, multi_pod: bool = False, tp: int | None = None,
         if multi_pod:
             shape = (2, *shape)
             axes = ("pod", *axes)
-        return jax.make_mesh(shape, axes)
+        return auto_mesh(shape, axes)
     shape = (2, 256 // tp, tp) if multi_pod else (256 // tp, tp)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def data_axes(mesh) -> tuple[str, ...]:

@@ -463,7 +463,6 @@ def moe_forward_sorted(p, cfg: ModelConfig, x):
             p["experts"], cfg, cap)
         out = out.reshape(B, S, D)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         mesh = ctx.mesh
         dp = ctx.dp
@@ -484,10 +483,10 @@ def moe_forward_sorted(p, cfg: ModelConfig, x):
                 capl, ep_axes=ep_axes, tp_axis="model", n_ep=n_ep)
             return out.reshape(hl.shape)
 
-        out = shard_map(
+        out = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(dp, None, None), P(dp, None, None), w_specs),
-            out_specs=P(dp, None, None), check_rep=False,
+            out_specs=P(dp, None, None), check_vma=False,
         )(h, probs.astype(jnp.float32), p["experts"])
     if e.shared_expert:
         out = out + _ffn(p["shared"], cfg, h).astype(out.dtype)

@@ -70,10 +70,22 @@ def _init_period(kg: KeyGen, cfg: ModelConfig, tag: str, with_cross: bool):
     return period
 
 
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_period(stack, j, tree):
+    return jax.tree.map(lambda s, leaf: s.at[j].set(leaf), stack, tree)
+
+
 def _stack_periods(init_one: Callable, n: int):
-    """Initialise n periods and stack leaves along axis 0."""
-    trees = [init_one(j) for j in range(n)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *trees)
+    """Initialise n periods into leaves stacked along axis 0.  Each
+    period is written into its row of the stack in place (the stack is
+    donated), so at most one period is held twice — stacking a list of
+    finished periods would hold every layer twice at its peak."""
+    tree = init_one(0)
+    stack = jax.tree.map(lambda leaf: jnp.zeros((n, *leaf.shape), leaf.dtype),
+                         tree)
+    for j in range(n):
+        stack = _put_period(stack, j, tree if j == 0 else init_one(j))
+    return stack
 
 
 def init_params(cfg: ModelConfig, rng) -> dict:
@@ -223,20 +235,42 @@ def slice_periods(stacked, lo: int, hi: int):
     cache out of the stacked representation with the same arithmetic, so
     `prefill_blocks`/`decode_blocks` run unchanged over the sub-stack —
     the staged computation is the same scan body the whole-model path
-    compiles, just over fewer periods."""
+    compiles, just over fewer periods.  The slice is a copy; a stage that
+    shares the device of the whole stack passes ``periods=`` instead."""
     return jax.tree.map(lambda leaf: leaf[lo:hi], stacked)
 
 
+def _scan_periods(body, x, stacked_params, periods, *xs):
+    """``lax.scan`` of ``body(h, (period_params, *xs))`` over the periods.
+
+    ``periods`` None scans every period of ``stacked_params``; an int32
+    index vector scans those periods only, read from the whole stack
+    inside the loop, so a stage that owns periods [lo, hi) of a stack it
+    shares with other stages needs no copy of its slice."""
+    if periods is None:
+        return jax.lax.scan(body, x, (stacked_params, *xs))
+
+    def indexed(h, idx_xs):
+        i, *rest = idx_xs
+        pp = jax.tree.map(
+            lambda leaf: jax.lax.dynamic_index_in_dim(leaf, i, keepdims=False),
+            stacked_params)
+        return body(h, (pp, *rest))
+    return jax.lax.scan(indexed, x, (periods, *xs))
+
+
 def prefill_blocks(cfg: ModelConfig, stacked_params, x, positions, *,
-                   cap: int, enc_out=None, impl=None):
+                   cap: int, enc_out=None, impl=None, periods=None):
     """Prompt pass over a (sub-)stack of periods: scan the prefill body
-    (attention/mamba with cache construction) over ``stacked_params``.
-    Returns (hidden, stacked per-period caches).  The whole-model
-    `prefill` is embed -> this over ``params["layers"]`` -> norm/head; a
-    pipeline block stage is this over `slice_periods` of the stack."""
+    (attention/mamba with cache construction) over ``stacked_params``
+    (or over its ``periods``, an int32 index vector — see
+    `_scan_periods`).  Returns (hidden, stacked per-period caches).  The
+    whole-model `prefill` is embed -> this over ``params["layers"]`` ->
+    norm/head; a pipeline block stage is this over its own periods."""
     B, S, _ = x.shape
 
-    def body(h, period_params):
+    def body(h, xs):
+        (period_params,) = xs
         period_cache = {}
         for i, (mixer, mlp) in enumerate(cfg.block_pattern):
             pp = period_params[f"pos{i}"]
@@ -292,7 +326,7 @@ def prefill_blocks(cfg: ModelConfig, stacked_params, x, positions, *,
             period_cache[f"pos{i}"] = c
         return h, period_cache
 
-    return jax.lax.scan(body, x, stacked_params)
+    return _scan_periods(body, x, stacked_params, periods)
 
 
 def prefill(cfg: ModelConfig, params, batch, *, capacity: int | None = None,
@@ -322,9 +356,10 @@ def prefill(cfg: ModelConfig, params, batch, *, capacity: int | None = None,
 
 
 def decode_blocks(cfg: ModelConfig, stacked_params, stacked_cache, x, pos, *,
-                  impl=None):
+                  impl=None, periods=None):
     """One decode step over a (sub-)stack of periods: scan the decode body
-    over (params, cache) period pairs.  Returns (hidden, new caches).
+    over (params, cache) period pairs (``periods`` as in
+    `prefill_blocks`).  Returns (hidden, new caches).
     The whole-model `decode_step` is embed -> this -> norm/head; a
     pipeline block stage runs it over its resident cache slice.
 
@@ -368,7 +403,7 @@ def decode_blocks(cfg: ModelConfig, stacked_params, stacked_cache, x, pos, *,
             new_cache[f"pos{i}"] = c
         return h, new_cache
 
-    return jax.lax.scan(body, x, (stacked_params, stacked_cache))
+    return _scan_periods(body, x, stacked_params, periods, stacked_cache)
 
 
 def decode_cache_structs(cfg: ModelConfig, stacked_params, batch: int,

@@ -128,7 +128,6 @@ def make_compressed_sync(mesh, axis: str = "data"):
     ``axis`` — row i is device i's unreduced gradient.  ``synced`` has the
     same stacked layout; every row equals the EF-corrected int8-ring mean.
     """
-    from jax.experimental.shard_map import shard_map
     n = mesh.shape[axis]
 
     def body(g_tree, err_tree):
@@ -147,8 +146,8 @@ def make_compressed_sync(mesh, axis: str = "data"):
 
     def sync(local_grads, state: CompressionState):
         spec = jax.tree.map(lambda _: P(axis), local_grads)
-        f = shard_map(body, mesh=mesh, in_specs=(spec, spec),
-                      out_specs=(spec, spec), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec),
+                      out_specs=(spec, spec), check_vma=False)
         synced, new_err = f(local_grads, state.err)
         return synced, CompressionState(err=new_err)
 

@@ -168,6 +168,11 @@ class AotProgram:
     def n_compiled(self) -> int:
         return len(self._compiled)
 
+    def compiled_texts(self) -> list[str]:
+        """Compiled HLO of every executable built so far — where a Pallas
+        kernel was lowered for a TPU it shows as ``tpu_custom_call``."""
+        return [exe.as_text() for exe in self._compiled.values()]
+
     def __call__(self, *args):
         if _has_tracer(args):             # composing under vjp/eval_shape/jit
             return self._jit(*args)

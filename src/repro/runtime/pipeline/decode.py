@@ -8,9 +8,11 @@ EOS or its budget — traffic whose length is decided *by the pipeline's own
 output*.  This module runs that shape on the same executor core:
 
   * stages are built from the *same model code* the single-device server
-    runs — `models/lm.prefill_blocks` / `decode_blocks` over
-    `slice_periods` of the stacked parameters — so a pipelined serve is
-    token-identical to `LMServer.serve_round` under greedy sampling;
+    runs — `models/lm.prefill_blocks` / `decode_blocks` over the stage's
+    periods of the stacked parameters — so a pipelined serve is
+    token-identical to `LMServer.serve_round` under greedy sampling; on
+    the device that holds the model the stages read its one copy of the
+    weights (`_StageLayers`);
   * every block stage keeps its **KV/SSM cache slice resident on its
     placement slice**: the prefill op constructs the stage's cache shard
     on the stage's device, decode ops update it **in place** — the
@@ -73,24 +75,38 @@ def _embed_prefill_fn(cfg: ModelConfig):
     return fn
 
 
+# A block-owning stage's params are {"layers": stack, "periods": int32
+# index vector}: the periods it runs, read out of ``stack`` inside the
+# program (`lm._scan_periods`).  On the device that holds the whole model
+# the stack is the model's own (no copy); elsewhere it is the stage's
+# slice, indexed from 0.
 def _block_prefill_fn(cfg: ModelConfig, impl: str | None = None):
     def fn(p, x, cap):
         S = x.shape[1]
-        return lm.prefill_blocks(cfg, p, x, jnp.arange(S), cap=cap, impl=impl)
+        return lm.prefill_blocks(cfg, p["layers"], x, jnp.arange(S), cap=cap,
+                                 impl=impl, periods=p["periods"])
     return fn
 
 
 def _block_decode_fn(cfg: ModelConfig, impl: str | None = None):
     def fn(p, cache, x, pos):
-        return lm.decode_blocks(cfg, p, cache, x, pos, impl=impl)
+        return lm.decode_blocks(cfg, p["layers"], cache, x, pos, impl=impl,
+                                periods=p["periods"])
     return fn
+
+
+def _logits(cfg: ModelConfig, p, h):
+    """Final norm + head on the last position.  ``p["w"]`` is the
+    embedding itself when the head is tied: transposed inside the
+    program, so no transposed copy is ever stored."""
+    h = rmsnorm(h[:, -1:], p["norm"], cfg.norm_eps)
+    w = p["w"].T if cfg.tie_embeddings else p["w"]
+    return h @ w.astype(h.dtype)
 
 
 def _head_fn(cfg: ModelConfig):
     def fn(p, x):
-        h = x[:, -1:]
-        h = rmsnorm(h, p["norm"], cfg.norm_eps)
-        return h @ p["w"].astype(h.dtype)
+        return _logits(cfg, p, x)
     return fn
 
 
@@ -114,11 +130,9 @@ def _fused_prefill_fn(cfg: ModelConfig, has_embed: bool, has_head: bool,
             x = jax.lax.optimization_barrier(x)
         S = x.shape[1]
         y, cache = lm.prefill_blocks(cfg, p["layers"], x, jnp.arange(S),
-                                     cap=cap, impl=impl)
+                                     cap=cap, impl=impl, periods=p["periods"])
         if has_head:
-            h = jax.lax.optimization_barrier(y)[:, -1:]
-            h = rmsnorm(h, p["norm"], cfg.norm_eps)
-            y = h @ p["w"].astype(h.dtype)
+            y = _logits(cfg, p, jax.lax.optimization_barrier(y))
         return y, cache
     return fn
 
@@ -132,11 +146,9 @@ def _fused_decode_fn(cfg: ModelConfig, has_embed: bool, has_head: bool,
             x = jnp.take(p["embed"], x, axis=0).astype(dt)
             x = jax.lax.optimization_barrier(x)
         y, cache = lm.decode_blocks(cfg, p["layers"], cache, x, pos,
-                                    impl=impl)
+                                    impl=impl, periods=p["periods"])
         if has_head:
-            h = jax.lax.optimization_barrier(y)[:, -1:]
-            h = rmsnorm(h, p["norm"], cfg.norm_eps)
-            y = h @ p["w"].astype(h.dtype)
+            y = _logits(cfg, p, jax.lax.optimization_barrier(y))
         return y, cache
     return fn
 
@@ -152,6 +164,33 @@ class _StageDesc:
     has_embed: bool
     span: tuple[int, int] | None
     has_head: bool
+
+
+class _StageLayers:
+    """Where each block stage reads its periods from.  A stage on a device
+    that holds the whole stacked ``layers`` indexes that stack; elsewhere
+    the stage's slice is copied to its device once, shared by every
+    replica placed there."""
+
+    def __init__(self, layers):
+        self.layers = layers
+        self.home = set().union(*(leaf.devices()
+                                  for leaf in jax.tree.leaves(layers)
+                                  if isinstance(leaf, jax.Array)))
+        self._slices: dict = {}
+
+    def on(self, dev, lo: int, hi: int) -> dict:
+        if self.home == {dev}:
+            stack, first = self.layers, lo
+        else:
+            key = (dev, lo, hi)
+            if key not in self._slices:
+                self._slices[key] = jax.device_put(
+                    lm.slice_periods(self.layers, lo, hi), dev)
+            stack, first = self._slices[key], 0
+        periods = jax.device_put(
+            jnp.arange(first, first + hi - lo, dtype=jnp.int32), dev)
+        return {"layers": stack, "periods": periods}
 
 
 # ===========================================================================
@@ -176,6 +215,9 @@ class _Group:
     t_prefill_done: float = 0.0
     t_last: float = 0.0
     decode_done_s: list = field(default_factory=list)
+    logits: list = field(default_factory=list)
+    # head logits of the prefill and of each decode step, kept only when
+    # the serve asks for them (``keep_logits``)
     fed: list = field(default_factory=list)
     # token history: fed[j] is the (B,) token batch fed back for decode
     # step j.  out_tokens is NOT enough to replay a cache — done slots
@@ -526,8 +568,10 @@ class _ServeRun:
                  temperature: float | None = None,
                  pause_at: int | None = None,
                  open_groups: int | None = None,
-                 feedback_capacity: int | None = None):
+                 feedback_capacity: int | None = None,
+                 keep_logits: bool = False):
         self.pipe = pipe
+        self.keep_logits = keep_logits
         self.groups = groups
         self.eos_id = eos_id
         self.temperature = temperature
@@ -565,6 +609,8 @@ class _ServeRun:
         step (or retire the group) — `LMServer.serve_round` bookkeeping,
         verbatim, so completions are token-identical."""
         g = self.groups[self.gid_of[op.seq]]
+        if self.keep_logits:
+            g.logits.append(logits)
         nxt = np.asarray(self.pipe._sample(logits, g.gid, self.temperature))
         if op.kind == "P":
             g.t_prefill_done = t_done - engine.t0
@@ -704,7 +750,8 @@ class DecodePipeline:
         self._init_params = params     # full tree (references, not copies):
         self.periods_per_stage = pps   # what elastic.rescale_serving needs
         self.seed = seed               # to rebuild this pipeline elsewhere
-        head_w = params["embed"].T if cfg.tie_embeddings else params["head"]
+        head_w = params["embed"] if cfg.tie_embeddings else params["head"]
+        stage_layers = _StageLayers(params["layers"])
 
         # stage list: embed, one per pps-period group, head — then the
         # fusion plan partitions that base chain into executed stages.
@@ -753,36 +800,30 @@ class DecodePipeline:
             if desc.has_head:
                 owners.append("head")
             head_p = {"norm": params["final_norm"], "w": head_w}
-            if desc.span is None:
-                stage_p = ({"embed": params["embed"]} if desc.has_embed
-                           else head_p)
-            elif desc.has_embed or desc.has_head:
-                # fused stage: member param trees keyed by role — the ONE
-                # fused program reads them all (one dispatch for the
-                # whole member sequence)
-                stage_p = {"layers": lm.slice_periods(params["layers"],
-                                                      *desc.span)}
-                if desc.has_embed:
-                    stage_p["embed"] = params["embed"]
-                if desc.has_head:
-                    stage_p.update(head_p)
-            else:
-                stage_p = lm.slice_periods(params["layers"], *desc.span)
+            # fused stage: member param trees keyed by role — the ONE
+            # fused program reads them all (one dispatch for the whole
+            # member sequence)
+            stage_p = {}
+            if desc.has_embed:
+                stage_p["embed"] = params["embed"]
+            if desc.has_head:
+                stage_p.update(head_p)
             # replica pool: every member owner's placement slices (same
             # rule as jax_pipe — nr x n_owners copies, each doing the
             # whole fused stage's work, same planned capacity)
             slices = [sl for owner in owners for sl in pl.replicas_of(owner)]
-            devs, reps = [], {}
-            for k, sl in enumerate(slices):
-                # decode stages are single-device jits: a tp>1 slice folds
-                # onto its first device (plan replicas, not intra-stage
-                # sharding, are what this backend executes)
-                dev = sl.resolve(devices)[0]
-                devs.append(dev)
-                reps[k] = jax.device_put(stage_p, dev)
-            if not devs:
-                devs = [devices[0]]
-                reps = {0: jax.device_put(stage_p, devices[0])}
+            # decode stages are single-device jits: a tp>1 slice folds
+            # onto its first device (plan replicas, not intra-stage
+            # sharding, are what this backend executes)
+            devs = [sl.resolve(devices)[0] for sl in slices] or [devices[0]]
+            reps = {}
+            for k, dev in enumerate(devs):
+                rep_p = dict(stage_p)
+                if desc.span is not None:
+                    rep_p.update(stage_layers.on(dev, *desc.span))
+                # device_put onto the device a leaf already lives on
+                # aliases it: the one-device case holds one copy in all
+                reps[k] = jax.device_put(rep_p, dev)
             self.stage_names.append(desc.name)
             self.stage_devices.append(devs)
             self.stage_params.append(reps)
@@ -1038,7 +1079,8 @@ class DecodePipeline:
               tracer=None, injector=None, health=None,
               pause_after_tokens: int | None = None,
               preflight: bool = True,
-              feedback_capacity: int | None = None) -> ServeRunResult:
+              feedback_capacity: int | None = None,
+              keep_logits: bool = False) -> ServeRunResult:
         """Serve ``prompts`` in ``group_size`` slot groups streamed
         concurrently through the pipeline.  Grouping, bucketing, and
         EOS/budget bookkeeping mirror `LMServer.serve_round` on each
@@ -1064,7 +1106,9 @@ class DecodePipeline:
         preflight was skipped).  ``feedback_capacity``: override the
         head->embed stream's capacity (default ``max(2, n_groups)``) —
         mainly for demonstrating that an undersized feedback path is
-        rejected statically."""
+        rejected statically.  ``keep_logits``: keep every head logit row
+        on its group (``groups[g].logits``: prefill, then each decode
+        step) for a logits comparison against another path."""
         if not prompts:
             raise ValueError("serve() needs at least one prompt")
         overlap = self.overlap if overlap is None else overlap
@@ -1108,7 +1152,8 @@ class DecodePipeline:
                         capacity_blocks=capacity_blocks, overlap=overlap,
                         temperature=temperature,
                         pause_at=pause_after_tokens,
-                        feedback_capacity=feedback_capacity)
+                        feedback_capacity=feedback_capacity,
+                        keep_logits=keep_logits)
         for g in groups:
             run.enqueue("P", g.gid, 0)
         res, engine = self._launch(run, group_of, overlap=overlap,

@@ -96,11 +96,12 @@ class LMServer:
                  eos_id: int = 1, params=None, seed: int = 0,
                  mesh=None, temperature: float = 0.0, pipeline=None,
                  tracer=None, injector=None, health=None,
-                 preflight: bool = True, impl: str | None = None):
+                 preflight: bool = True, impl: str | None = None,
+                 keep_logits: bool = False):
         """``pipeline``: a `runtime.pipeline.DecodePipeline` — when set,
         ``serve``/``serve_round`` stream request groups through it instead
-        of the single-device prefill/decode loop.  Build it with the same
-        ``seed`` (or pass the server's ``params``) for token parity.
+        of the single-device prefill/decode loop; without ``params`` the
+        server shares the pipeline's weights instead of building its own.
         ``injector`` (a `failures.ReplicaFaultPlan`) and ``health`` (a
         `pipeline.health.HealthController`) ride along on every pipelined
         serve — chaos drills and self-healing, pipelined backend only.
@@ -109,7 +110,10 @@ class LMServer:
         single-device backend has no plan to verify either way).
         ``impl``: kernel implementation for every model call
         (`kernels.ops.resolve_impl` tier — None = auto; ``"ref"`` pins
-        the bitwise-historical decode path for A/B runs)."""
+        the bitwise-historical decode path for A/B runs).
+        ``keep_logits``: pipelined serves keep every head logit row on
+        their groups (``last_run.groups[g].logits``) for a logits
+        comparison against `forced_logits`."""
         self.cfg = cfg
         self.max_batch = max_batch
         self.eos_id = eos_id
@@ -122,9 +126,13 @@ class LMServer:
         self.injector = injector     # optional ReplicaFaultPlan (chaos)
         self.health = health         # optional HealthController
         self.impl = impl
+        self.keep_logits = keep_logits
+        self.last_run = None         # the last pipelined ServeRunResult
         self.model = build_model(cfg, impl)
-        self.params = params if params is not None \
-            else self.model.init(jax.random.PRNGKey(seed))
+        if params is None:             # a pipeline already holds the weights
+            params = pipeline._init_params if pipeline is not None \
+                else self.model.init(jax.random.PRNGKey(seed))
+        self.params = params
         self.stats = ServeStats()
         self._prefill = jax.jit(
             lambda p, batch, cap: self.model.prefill(p, batch, capacity=cap),
@@ -203,6 +211,22 @@ class LMServer:
                            prefill_s=t_prefill, decode_s=t_decode)
                 for i, r in enumerate(reqs)]
 
+    def forced_logits(self, tokens, fed, cap: int) -> list:
+        """Logits of this server's own prefill and decode programs on a
+        fixed token history: ``tokens`` (B, bucket) right-aligned prompts,
+        then one decode step per (B,) row of ``fed``.  Returns the (B, 1,
+        vocab) logits of the prefill and of each step.  Two paths fed the
+        same history are compared on these, not on sampled tokens: with
+        random weights the largest logit changes on rounding."""
+        logits, cache = self._prefill(
+            self.params, {"tokens": jnp.asarray(tokens, jnp.int32)}, cap)
+        out = [logits]
+        for f in fed:
+            logits, cache = self._decode(
+                self.params, cache, jnp.asarray(f, jnp.int32)[:, None])
+            out.append(logits)
+        return out
+
     def serve(self, reqs: list[Request]) -> list[Completion]:
         """Drain a queue in max_batch-sized rounds.  The pipelined backend
         streams *all* rounds concurrently through the stage pipeline (each
@@ -233,7 +257,8 @@ class LMServer:
             eos_id=self.eos_id, group_size=self.max_batch,
             temperature=self.temperature, tracer=self.tracer,
             injector=self.injector, health=self.health,
-            preflight=self.preflight)
+            preflight=self.preflight, keep_logits=self.keep_logits)
+        self.last_run = run
         self.stats.requests += len(reqs)
         self.stats.rounds += len(run.groups)
         self.stats.slo = run.slo()

@@ -27,6 +27,7 @@ from ..checkpoint import AsyncCheckpointer, latest_step, restore_checkpoint
 from ..configs.base import ModelConfig, ShapeCfg
 from ..data import DataState, make_pipeline
 from ..launch import sharding as shd
+from ..launch.mesh import auto_mesh
 from ..launch.steps import abstract_params, abstract_opt_state, make_train_step
 from ..models import build_model
 from .failures import FailureInjector
@@ -37,7 +38,7 @@ def local_mesh(tp: int = 1):
     """Mesh over this process's devices: ("data", "model")."""
     n = len(jax.devices())
     assert n % tp == 0, f"{n} devices not divisible by tp={tp}"
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return auto_mesh((n // tp, tp), ("data", "model"))
 
 
 @dataclass

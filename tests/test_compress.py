@@ -60,13 +60,13 @@ _RING_CHECK = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     import sys
     sys.path.insert(0, "src")
+    from repro.launch.mesh import auto_mesh
     from repro.optim.compress import (CompressionState, compressed_mean,
                                       make_compressed_sync)
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     n = 8
     rng = np.random.default_rng(0)
     local = rng.normal(size=(8, 4096)).astype(np.float32)
@@ -74,8 +74,8 @@ _RING_CHECK = textwrap.dedent("""
     # 1. raw ring mean vs exact
     def body(x):
         return compressed_mean(x[0], "data", n)[None]
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                          out_specs=P("data"), check_rep=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                          out_specs=P("data"), check_vma=False))
     got = np.asarray(f(local))
     want = local.mean(axis=0)
     for r in range(8):

@@ -291,6 +291,44 @@ def test_attn_decode_step_matches_historical_body(impl, pos):
         assert c[leaf].dtype == c_ref[leaf].dtype
 
 
+@pytest.mark.parametrize("pos", [0, 15, 40])
+def test_fused_kernel_step_matches_historical_body(pos):
+    """At a lane-aligned head width (128) the step takes the single fused
+    Pallas kernel, which equals the op-by-op `"ref"` body (outputs and
+    the written cache slot) in growing, boundary and wrapped ring
+    states."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.kernels import fused_decode
+    from repro.models import blocks
+    from repro.models.common import KeyGen
+
+    tiny = get_config("tiny")
+    cfg = dataclasses.replace(tiny, attn=dataclasses.replace(
+        tiny.attn, n_heads=4, n_kv_heads=2, head_dim=128, qkv_bias=True))
+    a = cfg.attn
+    B, C = 2, 16
+    assert fused_decode.step_path("interpret", cfg.d_model, a.n_heads,
+                                  a.n_kv_heads, a.head_dim, C) == "fused"
+    p = blocks.init_attn(KeyGen(jax.random.PRNGKey(0)), cfg, "t")
+    rng = np.random.default_rng(17)
+    p = {k: (_rand(rng, v.shape, jnp.float32) * 0.1 if k.startswith("b")
+             else v) for k, v in p.items()}
+    cache = {k: _rand(rng, v.shape, jnp.float32) * 0.1 for k, v in
+             blocks.init_attn_cache(cfg, B, C, jnp.float32).items()}
+    x = _rand(rng, (B, 1, cfg.d_model), jnp.float32)
+    o_ref, c_ref = blocks.attn_decode(p, cfg, x, cache, jnp.int32(pos),
+                                      impl="ref")
+    o, c = blocks.attn_decode(p, cfg, x, cache, jnp.int32(pos),
+                              impl="interpret")
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               atol=5e-5, rtol=5e-5)
+    for leaf in ("k", "v"):
+        np.testing.assert_allclose(np.asarray(c[leaf]),
+                                   np.asarray(c_ref[leaf]),
+                                   atol=5e-5, rtol=5e-5)
+
+
 def test_cross_attn_decode_dispatches_like_self_attn():
     from repro.configs import get_config
     from repro.models import blocks

@@ -74,7 +74,8 @@ _SHARDED = textwrap.dedent("""
                           jnp.float32)
     ref = blocks.moe_forward(p, cfg, x)          # unsharded einsum oracle
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((4, 2), ("data", "model"))
     ctx = sc.from_mesh(mesh, ep_data=True)
     # place params/inputs as the launcher would (experts on "data",
     # F on "model"; batch on "data")

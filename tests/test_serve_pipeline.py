@@ -5,8 +5,8 @@ Acceptance contract:
     completions to the single-device ``serve_round`` (greedy sampling) —
     in-process and on an 8-device pool (subprocess);
   * per-stage prefill/decode math is the *same code* the single-device
-    path runs (`models/lm.prefill_blocks` / `decode_blocks` over
-    `slice_periods`);
+    path runs (`models/lm.prefill_blocks` / `decode_blocks` over each
+    stage's periods of the shared stack);
   * `channels.StreamChannel` carries the continuous decode token stream
     with open/close semantics;
   * the graph-generic engine drives dynamically-growing op queues to
@@ -62,6 +62,45 @@ def test_pipelined_server_token_identical(decode_setup):
         assert a.uid == b.uid
         assert a.tokens == b.tokens, (a.uid, a.tokens, b.tokens)
         assert a.prompt_len == b.prompt_len
+
+
+def test_pipelined_serve_logits_match_single_device(decode_setup):
+    """The head logits a pipelined serve keeps (``keep_logits``) are the
+    single-device server's logits on the same token history — prefill
+    and every decode step — and the server serves the pipeline's own
+    weights instead of building a second tree."""
+    plan, stg = decode_setup
+    pipe = DecodePipeline(tiny, stg, plan)
+    srv = LMServer(tiny, max_batch=4, pipeline=pipe, keep_logits=True)
+    assert srv.params is pipe._init_params
+    srv.serve(_reqs(8, max_new=5))
+    single = LMServer(tiny, max_batch=4, params=srv.params)
+    for g in srv.last_run.groups:
+        want = single.forced_logits(g.tokens, g.fed, g.cap)
+        assert len(g.logits) == len(want) == len(g.fed) + 1
+        for got, ref in zip(g.logits, want):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_one_device_pipeline_holds_one_copy_of_weights(decode_setup):
+    """Folded onto one device, every stage reads the caller's parameter
+    buffers: block stages index the shared stack by period, and the tied
+    or untied head is the stored matrix, not a transposed copy."""
+    import jax
+    plan, stg = decode_setup
+    pipe = DecodePipeline(tiny, stg, plan, devices=jax.devices()[:1])
+    full = pipe._init_params
+    ptrs = {l.unsafe_buffer_pointer() for l in jax.tree.leaves(full)}
+    seen = set()
+    for s, desc in enumerate(pipe.stage_descs):
+        for p in pipe.stage_params[s].values():
+            leaves = jax.tree.leaves(
+                {k: v for k, v in p.items() if k != "periods"})
+            seen |= {l.unsafe_buffer_pointer() for l in leaves}
+            if desc.span is not None:
+                lo, hi = desc.span
+                assert list(np.asarray(p["periods"])) == list(range(lo, hi))
+    assert seen <= ptrs, "a stage holds a copy of the model's weights"
 
 
 def test_pipelined_server_respects_budgets(decode_setup):

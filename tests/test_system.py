@@ -4,6 +4,8 @@ These run the REAL training loop (reduced configs) on CPU — they assert the
 pod-scale contracts: restart-from-checkpoint transparency, bitwise data
 replay, straggler flagging, serving consistency.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -12,7 +14,12 @@ from repro.runtime import (FailureInjector, StragglerMonitor,
                            TrainLoopConfig, run_resilient, train_loop)
 from repro.runtime.server import LMServer, Request
 
-CFG = get_config("qwen2.5-3b").reduced()
+# The trainer tests keep the model they were calibrated on: float32 master
+# weights (the published bfloat16 would round away AdamW's small updates)
+# and an untied head (the tied one starts at the uniform loss, so 40 steps
+# of bigram data move it less than the 0.1 the learning test asks for).
+CFG = dataclasses.replace(get_config("qwen2.5-3b").reduced(),
+                          param_dtype="float32", tie_embeddings=False)
 
 
 def _loop(tmp, **kw):
