@@ -33,12 +33,15 @@ the ahead-of-time executables themselves:
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import jax
+
+from .trace import SPAN_COMPILE, SPAN_STAGE, span
 
 
 @dataclass
@@ -121,15 +124,27 @@ class AotProgram:
     the wrapped jit so the program stays composable.  ``precompile``
     takes the same positional args — concrete or `ShapeDtypeStruct` —
     and builds the executable without running it.
+
+    The jitted function carries ``name``, so the compiled module is
+    ``jit_<name>`` (XLA turns ``+`` into ``_``) in a device trace, and an
+    op body running this program is the profiler span ``stage.<name>``
+    (`stage_span`).
     """
 
     def __init__(self, fn, *, name: str = "", stats: CompileStats | None = None,
                  static_argnums: tuple = (), donate_argnums: tuple = ()):
         self.fn = fn
         self.name = name or getattr(fn, "__name__", "program")
+        self.stage_span = SPAN_STAGE + self.name
+        self._compile_span = SPAN_COMPILE + self.name
         self.stats = stats if stats is not None else CompileStats()
         self._static = tuple(static_argnums)
-        self._jit = jax.jit(fn, static_argnums=static_argnums,
+
+        @functools.wraps(fn)
+        def named(*args):
+            return fn(*args)
+        named.__name__ = named.__qualname__ = self.name
+        self._jit = jax.jit(named, static_argnums=static_argnums,
                             donate_argnums=donate_argnums)
         self._compiled: dict = {}
         # op bodies run on the engine's worker pool: the compile path and
@@ -152,7 +167,8 @@ class AotProgram:
             if exe is not None:          # another thread won the race —
                 return exe               # one compile, not two stalls
             t0 = time.perf_counter()
-            exe = self._jit.lower(*args).compile()
+            with span(self._compile_span, on_miss=on_miss):
+                exe = self._jit.lower(*args).compile()
             self.stats.note(self.name, time.perf_counter() - t0, on_miss)
             self._compiled[key] = exe
             return exe

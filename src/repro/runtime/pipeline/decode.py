@@ -62,6 +62,8 @@ from .aot import AotProgram, CompileStats
 from .channels import Fifo, StreamChannel, check_not_donated
 from .engine import AsyncResult, Engine, EngineResult, Op, describe_position
 from .placement import Placement, place
+from .trace import (SPAN_ENGINE_END, SPAN_ENGINE_START, SPAN_HEAD_SAMPLE,
+                    SPAN_SERVE_FINISH, SPAN_SERVE_PREPARE, mark, span)
 
 
 # ===========================================================================
@@ -207,6 +209,7 @@ class _Group:
     bucket: int
     cap: int
     budget: np.ndarray
+    prompt_tokens: int = 0             # real (unpadded) prompt tokens
     done: np.ndarray = None
     out_tokens: list = None
     steps: int = 0                     # completed decode steps
@@ -398,19 +401,21 @@ class _ServeStageProgram:
         desc = pipe.stage_descs[s]
         dev = pipe.stage_devices[s][rep]
         params = pipe.stage_params[s][rep]
+        at = (s, kind)
         if desc.span is None:                             # lone embed / head
             prog = pipe._embed if desc.has_embed else pipe._head
-            return (_run_stage, (prog, params, (payload,), dev))
+            return (_run_stage, (prog, params, (payload,), dev, at))
         if desc.has_embed or desc.has_head:               # fused stage
             pre, dec = pipe._fused[(desc.has_embed, desc.has_head)]
         else:                                             # plain block stage
             pre, dec = pipe._block_prefill, pipe._block_decode
         if kind == "P":
-            return (_run_stage_static_cap, (pre, params, payload, g.cap, dev))
+            return (_run_stage_static_cap,
+                    (pre, params, payload, g.cap, dev, at))
         cache = self.caches[gid]
         return (_run_stage,
                 (dec, params,
-                 (cache, payload, jnp.asarray(pos, jnp.int32)), dev))
+                 (cache, payload, jnp.asarray(pos, jnp.int32)), dev, at))
 
     def dispatch(self, op: Op, driver):
         s, S, run = self.s, self.S, self.run
@@ -541,21 +546,24 @@ class _ServeStageProgram:
             lambda q: f"{q[0]}(gid={q[1]},seq={q[2]})")
 
 
-def _run_stage(fn, params, args, dev):
+def _run_stage(fn, params, args, dev, at):
     """Dispatch one stage program and return without a host sync: the
     engine retires the op off the watch set's completion future.  Watch
     the first output leaf only — a block stage's (hidden, cache) pair
     materialises together (one executable), and the resident cache slice
-    is rebound at retirement, after that future fires."""
-    args = tuple(jax.device_put(a, dev) if hasattr(a, "shape") else a
-                 for a in args)
-    out = fn(params, *args)
+    is rebound at retirement, after that future fires.  ``at``: the
+    (stage index, op kind) the op's ``stage.<program>`` span carries."""
+    with span(fn.stage_span, stage=at[0], kind=at[1]):
+        args = tuple(jax.device_put(a, dev) if hasattr(a, "shape") else a
+                     for a in args)
+        out = fn(params, *args)
     return AsyncResult((out,), watch=jax.tree.leaves(out)[:1])
 
 
-def _run_stage_static_cap(fn, params, x, cap, dev):
-    x = jax.device_put(x, dev)
-    out = fn(params, x, cap)
+def _run_stage_static_cap(fn, params, x, cap, dev, at):
+    with span(fn.stage_span, stage=at[0], kind=at[1]):
+        x = jax.device_put(x, dev)
+        out = fn(params, x, cap)
     return AsyncResult((out,), watch=jax.tree.leaves(out)[:1])
 
 
@@ -596,8 +604,20 @@ class _ServeRun:
         self.feedback = StreamChannel(block=1, capacity_blocks=1,
                                       min_capacity=fb_cap)
         self.open_groups = len(groups) if open_groups is None else open_groups
+        # what this run's ops execute, for the ``serve.engine.end`` mark:
+        # token slots (batch x bucket per prefill, batch per decode step)
+        # and the real tokens among them (prompt tokens, and each
+        # generated token after a request's first)
+        self.slots = 0
+        self.real_tokens = 0
 
     def enqueue(self, kind: str, gid: int, pos: int) -> int:
+        g = self.groups[gid]
+        if kind == "P":
+            self.slots += g.batch * g.bucket
+            self.real_tokens += g.prompt_tokens
+        else:
+            self.slots += g.batch
         seq = len(self.gid_of)
         self.gid_of.append(gid)
         for p in self.programs:
@@ -611,7 +631,9 @@ class _ServeRun:
         g = self.groups[self.gid_of[op.seq]]
         if self.keep_logits:
             g.logits.append(logits)
-        nxt = np.asarray(self.pipe._sample(logits, g.gid, self.temperature))
+        with span(SPAN_HEAD_SAMPLE, kind=op.kind, batch=g.batch):
+            nxt = np.asarray(self.pipe._sample(logits, g.gid,
+                                               self.temperature))
         if op.kind == "P":
             g.t_prefill_done = t_done - engine.t0
             g.cur = nxt.astype(np.int32)
@@ -625,6 +647,7 @@ class _ServeRun:
                 if not g.done[i] and g.steps < g.budget[i]:
                     tok = int(nxt[i])
                     g.out_tokens[i].append(tok)
+                    self.real_tokens += 1
                     if tok == self.eos_id:
                         g.done[i] = True
                 elif not g.done[i]:
@@ -1116,49 +1139,60 @@ class DecodePipeline:
             max_new = [max_new] * len(prompts)
         if len(max_new) != len(prompts):
             raise ValueError("max_new must be a scalar or match prompts")
-        groups: list[_Group] = []
-        group_of: list[int] = []
-        for gid, lo in enumerate(range(0, len(prompts), group_size)):
-            chunk = prompts[lo:lo + group_size]
-            budgets = np.array(max_new[lo:lo + group_size])
-            plen = max(len(p) for p in chunk)
-            bucket = _bucket(plen)
-            # same capacity clamp as lm.prefill: SWA archs ring-buffer the
-            # cache at the attention window — an unclamped cap would let
-            # the pipeline attend further back than the single-device
-            # server and break token parity on windowed configs
-            cap = blocks.attn_cache_capacity(
-                self.cfg, bucket + int(budgets.max()))
-            toks = np.zeros((len(chunk), bucket), np.int32)
-            for i, p in enumerate(chunk):          # right-align prompts so
-                toks[i, bucket - len(p):] = p      # last token is real
-            groups.append(_Group(
-                gid=gid, tokens=toks, bucket=bucket, cap=cap,
-                budget=budgets, out_tokens=[None] * len(chunk)))
-            group_of.extend([gid] * len(chunk))
+        # everything before the engine starts: group and bucket the
+        # prompts, preflight the plan, warm every group shape, queue the
+        # prefills and build the engine
+        with span(SPAN_SERVE_PREPARE, requests=len(prompts)) as prep:
+            groups: list[_Group] = []
+            group_of: list[int] = []
+            for gid, lo in enumerate(range(0, len(prompts), group_size)):
+                chunk = prompts[lo:lo + group_size]
+                budgets = np.array(max_new[lo:lo + group_size])
+                plen = max(len(p) for p in chunk)
+                bucket = _bucket(plen)
+                # same capacity clamp as lm.prefill: SWA archs ring-buffer
+                # the cache at the attention window — an unclamped cap
+                # would let the pipeline attend further back than the
+                # single-device server and break token parity on windowed
+                # configs
+                cap = blocks.attn_cache_capacity(
+                    self.cfg, bucket + int(budgets.max()))
+                toks = np.zeros((len(chunk), bucket), np.int32)
+                for i, p in enumerate(chunk):      # right-align prompts so
+                    toks[i, bucket - len(p):] = p  # last token is real
+                groups.append(_Group(
+                    gid=gid, tokens=toks, bucket=bucket, cap=cap,
+                    budget=budgets,
+                    prompt_tokens=sum(len(p) for p in chunk),
+                    out_tokens=[None] * len(chunk)))
+                group_of.extend([gid] * len(chunk))
 
-        report = None
-        if preflight:
-            report = self._preflight(
-                n_groups=len(groups), capacity_blocks=capacity_blocks,
-                feedback_capacity=feedback_capacity,
-                group_shapes=[(g.batch, g.bucket, g.cap) for g in groups])
+            report = None
+            if preflight:
+                report = self._preflight(
+                    n_groups=len(groups), capacity_blocks=capacity_blocks,
+                    feedback_capacity=feedback_capacity,
+                    group_shapes=[(g.batch, g.bucket, g.cap)
+                                  for g in groups])
 
-        if self.warmup:
+            if self.warmup:
+                for g in groups:
+                    self._warm_group_shape(g.batch, g.bucket, g.cap)
+
+            run = _ServeRun(self, groups, eos_id=eos_id,
+                            capacity_blocks=capacity_blocks,
+                            overlap=overlap,
+                            temperature=temperature,
+                            pause_at=pause_after_tokens,
+                            feedback_capacity=feedback_capacity,
+                            keep_logits=keep_logits)
             for g in groups:
-                self._warm_group_shape(g.batch, g.bucket, g.cap)
-
-        run = _ServeRun(self, groups, eos_id=eos_id,
-                        capacity_blocks=capacity_blocks, overlap=overlap,
-                        temperature=temperature,
-                        pause_at=pause_after_tokens,
-                        feedback_capacity=feedback_capacity,
-                        keep_logits=keep_logits)
-        for g in groups:
-            run.enqueue("P", g.gid, 0)
-        res, engine = self._launch(run, group_of, overlap=overlap,
-                                   tracer=tracer, injector=injector,
-                                   health=health, static_report=report)
+                run.enqueue("P", g.gid, 0)
+            engine = self._engine(run, overlap=overlap, tracer=tracer,
+                                  injector=injector, health=health,
+                                  static_report=report)
+            prep.set_metadata(groups=len(groups))
+        res = self._launch(run, engine, group_of)
         for g in groups:                       # run-relative group timings
             g.t_start = max(0.0, g.t_start - engine.t0)
         return res
@@ -1182,13 +1216,13 @@ class DecodePipeline:
         self.last_preflight = report
         return report.raise_if_errors("DecodePipeline.serve")
 
-    def _launch(self, run: "_ServeRun", group_of: list, *, overlap: bool,
-                tracer, injector, health,
-                static_report=None) -> tuple[ServeRunResult, Engine]:
-        """Wire channels, drive the engine to quiescence, fold the
-        engine result into a `ServeRunResult` (exporting a `ResumeState`
-        when the run admission-paused) — shared by `serve` and
-        `resume`."""
+    def _engine(self, run: "_ServeRun", *, overlap: bool, tracer, injector,
+                health, static_report=None) -> Engine:
+        """Wire channels and build the engine that drives ``run`` — shared
+        by `serve` and `resume`.  The engine writes the run's profiler
+        marks: ``serve.engine.start`` as it reads ``t0`` and
+        ``serve.engine.end`` (slots, real tokens, late compiles) as it
+        reads ``wall_s``."""
         names = self.stage_names
         fifo_map = {f"act{s}": run.acts[s] for s in range(len(run.acts))}
         fifo_map["feedback"] = run.feedback
@@ -1198,19 +1232,41 @@ class DecodePipeline:
                                   src=names[s], dst=names[s + 1])
             tracer.watch_fifo(run.feedback, "feedback",
                               src=names[-1], dst=names[0])
-        engine = Engine(run.programs, overlap=overlap,
-                        workers=self._n_workers(),
-                        replica_queue=self.replica_queue,
-                        tracer=tracer, fifos=fifo_map, injector=injector,
-                        on_tick=None if health is None else health.tick,
-                        tick_every=64 if health is None
-                        else health.check_every,
-                        static_report=static_report)
+        late0 = self.compile_stats.late
+
+        def end_mark(_engine):
+            mark(SPAN_ENGINE_END, slots=run.slots,
+                 real_tokens=run.real_tokens,
+                 late_compiles=self.compile_stats.late - late0)
+
+        return Engine(run.programs, overlap=overlap,
+                      workers=self._n_workers(),
+                      replica_queue=self.replica_queue,
+                      tracer=tracer, fifos=fifo_map, injector=injector,
+                      on_tick=None if health is None else health.tick,
+                      tick_every=64 if health is None
+                      else health.check_every,
+                      static_report=static_report,
+                      on_start=lambda _engine: mark(SPAN_ENGINE_START),
+                      on_end=end_mark)
+
+    def _launch(self, run: "_ServeRun", engine: Engine,
+                group_of: list) -> ServeRunResult:
+        """Drive the engine to quiescence and fold its result into a
+        `ServeRunResult` (exporting a `ResumeState` when the run
+        admission-paused) — shared by `serve` and `resume`."""
         with self.compile_stats.window():
             er = engine.run()
         assert run.feedback.exhausted, \
             "token stream not drained: a group retired with tokens in flight"
+        with span(SPAN_SERVE_FINISH):
+            return self._fold(run, er, group_of)
 
+    def _fold(self, run: "_ServeRun", er: EngineResult,
+              group_of: list) -> ServeRunResult:
+        """The engine's result, the run's tokens and fifo stats, and the
+        resume state of a paused run, as one `ServeRunResult`."""
+        names = self.stage_names
         res = ServeRunResult(
             tokens=[], group_of=group_of, groups=run.groups,
             stage_done_s=er.stage_done_s, stage_seconds=er.stage_seconds,
@@ -1237,7 +1293,7 @@ class DecodePipeline:
                                "caches": dict(run.programs[s].caches)}
                     for s in range(len(names))
                     if self.period_span[s] is not None})
-        return res, engine
+        return res
 
     def resume(self, state: ResumeState, *, capacity_blocks: int = 2,
                overlap: bool | None = None,
@@ -1283,12 +1339,13 @@ class DecodePipeline:
                    for v in state.stage_caches.values()}
         for s in range(S):
             prog = run.programs[s]
-            span = self.period_span[s]
-            donors = by_span.get(tuple(span)) if span is not None else None
+            p_span = self.period_span[s]
+            donors = by_span.get(tuple(p_span)) if p_span is not None \
+                else None
             for g in live:
                 k = 1 + g.steps        # every stage retired prefill +
                 prog.done_count[g.gid] = k     # g.steps decode ops
-                if span is None:
+                if p_span is None:
                     continue
                 if donors is not None and g.gid in donors:
                     prog.caches[g.gid] = jax.device_put(
@@ -1301,7 +1358,7 @@ class DecodePipeline:
             seq = run.enqueue("D", g.gid, g.bucket + g.steps)
             g.fed.append(g.cur.copy())
             run.feedback.push([(seq, (g.gid, g.cur[:, None]))], 0.0)
-        res, _engine = self._launch(run, state.group_of, overlap=overlap,
-                                    tracer=tracer, injector=injector,
-                                    health=health, static_report=report)
-        return res
+        engine = self._engine(run, overlap=overlap, tracer=tracer,
+                              injector=injector, health=health,
+                              static_report=report)
+        return self._launch(run, engine, state.group_of)

@@ -54,6 +54,7 @@ from typing import Any, Callable, Iterable, Protocol, runtime_checkable
 
 from ..failures import PipelineFailure, ReplicaFault
 from .channels import Fifo
+from .trace import SPAN_RETIRE, SPAN_SWEEP, SPAN_WAIT, span
 
 
 def steady_inverse(samples: Iterable[float], warmup_frac: float = 0.25,
@@ -329,7 +330,9 @@ class Engine(Driver):
                  workers: int = 8, replica_queue: int = 2,
                  tracer=None, fifos: dict | None = None,
                  injector=None, on_tick: Callable | None = None,
-                 tick_every: int = 64, static_report=None):
+                 tick_every: int = 64, static_report=None,
+                 on_start: Callable | None = None,
+                 on_end: Callable | None = None):
         """``tracer``: optional `trace.Tracer` — op spans, wait spans, and
         per-stage stall/starve accounting (off = zero-cost path).
         ``fifos``: {label: Fifo} for the deadlock report's occupancy
@@ -343,7 +346,10 @@ class Engine(Driver):
         `core.verify.VerificationReport` this run was preflighted with
         (None = preflight skipped) — a runtime deadlock cross-references
         it so the report says whether the wedge matches a static finding
-        or the plan was proven deadlock-free."""
+        or the plan was proven deadlock-free.  ``on_start(engine)`` /
+        ``on_end(engine)``: called from `run` right after ``t0`` is read
+        and right after ``wall_s`` is (a serve writes its profiler marks
+        there)."""
         super().__init__(tracer)
         self.programs = list(programs)
         self.fifos = dict(fifos or {})
@@ -354,6 +360,8 @@ class Engine(Driver):
         self.injector = injector
         self.on_tick = on_tick
         self.tick_every = max(1, tick_every)
+        self.on_start = on_start
+        self.on_end = on_end
         self._retired_n = 0
         self.result = EngineResult()
         self._busy = [[0] * max(1, p.n_replicas) for p in self.programs]
@@ -390,9 +398,10 @@ class Engine(Driver):
     def _settle(self, op: Op, result, t_done: float) -> None:
         """Retire a completed op, unwrapping an `AsyncResult` by appending
         the observed completion timestamp to its payload."""
-        if isinstance(result, AsyncResult):
-            result = result.payload + (t_done,)
-        self._retire(op, result)
+        with span(SPAN_RETIRE):
+            if isinstance(result, AsyncResult):
+                result = result.payload + (t_done,)
+            self._retire(op, result)
 
     def _abort(self, op: Op) -> None:
         """An op's body raised: free its channel credits and busy slot so
@@ -547,6 +556,8 @@ class Engine(Driver):
         from concurrent.futures import (FIRST_COMPLETED, ThreadPoolExecutor,
                                         wait)
         self.t0 = time.perf_counter()
+        if self.on_start is not None:
+            self.on_start(self)
         inflight = self._inflight           # future -> Op (worker running)
         pending = self._pending             # (Op, AsyncResult): body returned,
         #                                     device work still in flight
@@ -563,136 +574,149 @@ class Engine(Driver):
         try:
             while (any(p.pending() for p in self.programs)
                    or inflight or pending):
-                progressed = False
-                # downstream-first: consumers drain fifos before producers
-                for s in reversed(range(len(self.programs))):
-                    prog = self.programs[s]
-                    op = prog.peek()
-                    if op is None:
-                        if tr is not None and wait_since[s] is None:
-                            r = self.idle_reason_of(prog)
-                            if r is not None:
-                                wait_since[s] = (
-                                    time.perf_counter() - self.t0, r)
-                        continue
-                    if self._busy[s][op.rep] >= self.replica_queue:
-                        continue
-                    if prog.ready(op) is None:
-                        if tr is not None and wait_since[s] is None:
-                            wait_since[s] = (time.perf_counter() - self.t0,
-                                             self.wait_reason_of(prog))
-                        continue
-                    stall_s = 0.0
-                    if self.injector is not None:
-                        spec = self.injector.check(prog.name, op.rep, op.seq)
-                        if spec is not None and spec.kind == "crash":
-                            # the op consumed nothing yet: failover remaps
-                            # its routing and the next sweep re-peeks it
-                            # onto a surviving replica
-                            self._replica_fault(s, op.rep, spec.kind)
-                            progressed = True
+                # one scheduling pass: dispatch what is ready, collect
+                # finished worker bodies, retire observed device completions
+                with span(SPAN_SWEEP) as sweep:
+                    progressed = False
+                    n_dispatched = 0
+                    # downstream-first: consumers drain fifos first
+                    for s in reversed(range(len(self.programs))):
+                        prog = self.programs[s]
+                        op = prog.peek()
+                        if op is None:
+                            if tr is not None and wait_since[s] is None:
+                                r = self.idle_reason_of(prog)
+                                if r is not None:
+                                    wait_since[s] = (
+                                        time.perf_counter() - self.t0, r)
                             continue
-                        elif spec is not None:
-                            stall_s = spec.stall_s
-                    fn, args = prog.dispatch(op, self)
-                    if stall_s > 0.0:
-                        fn = _stalled(fn, stall_s)
-                    op.t_dispatch = time.perf_counter()
-                    self._busy[s][op.rep] += 1
-                    progressed = True
-                    if tr is not None:
-                        td = op.t_dispatch - self.t0
-                        if wait_since[s] is not None:
-                            t_w, (reason, edge) = wait_since[s]
-                            wait_since[s] = None
-                            tr.wait(prog.name, reason, edge, t_w, td)
-                            d = self.result.stage_wait_s.setdefault(
-                                prog.name, {})
-                            d[reason] = d.get(reason, 0.0) + (td - t_w)
-                        tr.op_dispatch(prog.name, op.rep, op.kind,
-                                       op.seq, op.chunk, td)
-                    if pool is None:
-                        # serial A/B baseline: dispatch, await, advance
+                        if self._busy[s][op.rep] >= self.replica_queue:
+                            continue
+                        if prog.ready(op) is None:
+                            if tr is not None and wait_since[s] is None:
+                                wait_since[s] = (
+                                    time.perf_counter() - self.t0,
+                                    self.wait_reason_of(prog))
+                            continue
+                        stall_s = 0.0
+                        if self.injector is not None:
+                            spec = self.injector.check(prog.name, op.rep,
+                                                       op.seq)
+                            if spec is not None and spec.kind == "crash":
+                                # the op consumed nothing yet: failover remaps
+                                # its routing and the next sweep re-peeks it
+                                # onto a surviving replica
+                                self._replica_fault(s, op.rep, spec.kind)
+                                progressed = True
+                                continue
+                            elif spec is not None:
+                                stall_s = spec.stall_s
+                        fn, args = prog.dispatch(op, self)
+                        if stall_s > 0.0:
+                            fn = _stalled(fn, stall_s)
+                        op.t_dispatch = time.perf_counter()
+                        self._busy[s][op.rep] += 1
+                        progressed = True
+                        n_dispatched += 1
+                        if tr is not None:
+                            td = op.t_dispatch - self.t0
+                            if wait_since[s] is not None:
+                                t_w, (reason, edge) = wait_since[s]
+                                wait_since[s] = None
+                                tr.wait(prog.name, reason, edge, t_w, td)
+                                d = self.result.stage_wait_s.setdefault(
+                                    prog.name, {})
+                                d[reason] = d.get(reason, 0.0) + (td - t_w)
+                            tr.op_dispatch(prog.name, op.rep, op.kind,
+                                           op.seq, op.chunk, td)
+                        if pool is None:
+                            # serial A/B baseline: dispatch, await, advance
+                            try:
+                                result, host_s = self._timed(fn, args)
+                            except ReplicaFault:
+                                self._abort(op)   # the op itself is lost too:
+                                self._replica_fault(s, op.rep, "crash",
+                                                    lost0=(op,))
+                                progressed = True
+                                continue
+                            except BaseException:
+                                self._abort(op)
+                                raise
+                            dispatch_s[prog.name] += host_s
+                            if isinstance(result, AsyncResult):
+                                try:        # a device error surfaces here —
+                                    with span(SPAN_WAIT,   # free credits
+                                              reason="device"):  # like the
+                                        result.block()
+                                except ReplicaFault:
+                                    self._abort(op)
+                                    self._replica_fault(s, op.rep, "crash",
+                                                        lost0=(op,))
+                                    progressed = True
+                                    continue
+                                except BaseException:  # old in-body sync did
+                                    self._abort(op)
+                                    raise
+                            self._settle(op, result, time.perf_counter())
+                        else:
+                            inflight[pool.submit(self._timed, fn,
+                                                 args)] = op
+                            self.result.max_inflight = max(
+                                self.result.max_inflight,
+                                len(inflight) + len(pending))
+                    # drain worker futures: a body either completed its op
+                    # synchronously (host compute) or handed back an
+                    # AsyncResult whose device work we watch below
+                    for f in [f for f in inflight if f.done()]:
+                        op = inflight.pop(f)
                         try:
-                            result, host_s = self._timed(fn, args)
+                            result, host_s = f.result()
                         except ReplicaFault:
-                            self._abort(op)     # the op itself is lost too:
-                            self._replica_fault(s, op.rep, "crash",
+                            self._abort(op)
+                            self._replica_fault(op.stage, op.rep, "crash",
                                                 lost0=(op,))
                             progressed = True
                             continue
                         except BaseException:
                             self._abort(op)
                             raise
-                        dispatch_s[prog.name] += host_s
+                        dispatch_s[self.programs[op.stage].name] += host_s
                         if isinstance(result, AsyncResult):
-                            try:        # a device error surfaces here —
-                                result.block()   # free credits like the
-                            except ReplicaFault:
-                                self._abort(op)
-                                self._replica_fault(s, op.rep, "crash",
-                                                    lost0=(op,))
-                                progressed = True
-                                continue
-                            except BaseException:  # old in-body sync did
-                                self._abort(op)
-                                raise
-                        self._settle(op, result, time.perf_counter())
-                    else:
-                        inflight[pool.submit(self._timed, fn, args)] = op
-                        self.result.max_inflight = max(
-                            self.result.max_inflight,
-                            len(inflight) + len(pending))
-                # drain worker futures: a body either completed its op
-                # synchronously (host compute) or handed back an
-                # AsyncResult whose device work we watch below
-                for f in [f for f in inflight if f.done()]:
-                    op = inflight.pop(f)
-                    try:
-                        result, host_s = f.result()
-                    except ReplicaFault:
-                        self._abort(op)
-                        self._replica_fault(op.stage, op.rep, "crash",
-                                            lost0=(op,))
-                        progressed = True
-                        continue
-                    except BaseException:
-                        self._abort(op)
-                        raise
-                    dispatch_s[self.programs[op.stage].name] += host_s
-                    if isinstance(result, AsyncResult):
-                        pending.append((op, result))
-                    else:
-                        self._settle(op, result, time.perf_counter())
-                        progressed = True
-                # retire device completions (completion futures, no host
-                # sync): ready watch sets observed this sweep
-                if pending:
-                    now = time.perf_counter()
-                    still = []
-                    for op, ar in pending:
-                        if ar.is_ready():
-                            self._settle(op, ar, now)
-                            progressed = True
+                            pending.append((op, result))
                         else:
-                            still.append((op, ar))
-                    pending = self._pending = still
+                            self._settle(op, result, time.perf_counter())
+                            progressed = True
+                    # retire device completions (completion futures, no host
+                    # sync): ready watch sets observed this sweep
+                    if pending:
+                        now = time.perf_counter()
+                        still = []
+                        for op, ar in pending:
+                            if ar.is_ready():
+                                self._settle(op, ar, now)
+                                progressed = True
+                            else:
+                                still.append((op, ar))
+                        pending = self._pending = still
+                    sweep.set_metadata(dispatched=n_dispatched)
                 if not progressed:
                     if inflight:
                         # with device work pending, wait bounded (a watch
                         # set may become ready before any worker future);
                         # with none, block until a worker finishes — no
                         # busy-poll stealing host CPU from the op bodies
-                        wait(list(inflight),
-                             timeout=self.POLL_S if pending else None,
-                             return_when=FIRST_COMPLETED)
+                        with span(SPAN_WAIT, reason="worker"):
+                            wait(list(inflight),
+                                 timeout=self.POLL_S if pending else None,
+                                 return_when=FIRST_COMPLETED)
                     elif pending:
                         # nothing dispatchable, no workers running: block
                         # on the oldest in-flight device op for an
                         # accurate completion timestamp
                         op, ar = pending.pop(0)
                         try:
-                            ar.block()
+                            with span(SPAN_WAIT, reason="device"):
+                                ar.block()
                         except ReplicaFault:
                             self._abort(op)
                             self._replica_fault(op.stage, op.rep, "crash",
@@ -714,6 +738,8 @@ class Engine(Driver):
             if pool is not None:
                 pool.shutdown(wait=True)
         self.result.wall_s = time.perf_counter() - self.t0
+        if self.on_end is not None:
+            self.on_end(self)
         return self.result
 
 

@@ -34,6 +34,7 @@ import numpy as np
 from .. import sharding_ctx as sctx
 from ..configs.base import ModelConfig
 from ..models import build_model
+from .pipeline.trace import SPAN_SERVE_FINISH, span
 
 
 @dataclass
@@ -65,12 +66,9 @@ class ServeStats:
     # per-decode-step wall gaps (single-device backend): the `int(nxt[i])`
     # conversions host-sync every step, so each gap is a real step time —
     # honest p50/p95 material, not a per-request mean smeared flat
-    slo: dict | None = None        # last pipelined serve's client-side
-    #                                percentiles (`ServeRunResult.slo()`);
-    #                                None on the single-device backend
 
     def summary(self) -> dict:
-        out = {
+        return {
             "requests": self.requests,
             "rounds": self.rounds,
             "prefill_tok_per_s": self.prefill_tokens / self.prefill_s
@@ -79,9 +77,6 @@ class ServeStats:
             if self.decode_s else 0.0,
             "decode_tokens": self.decode_tokens,
         }
-        if self.slo is not None:
-            out["slo"] = dict(self.slo)
-        return out
 
 
 def _bucket(n: int, lo: int = 16) -> int:
@@ -259,9 +254,13 @@ class LMServer:
             injector=self.injector, health=self.health,
             preflight=self.preflight, keep_logits=self.keep_logits)
         self.last_run = run
+        with span(SPAN_SERVE_FINISH):
+            return self._fold(reqs, run)
+
+    def _fold(self, reqs: list[Request], run) -> list[Completion]:
+        """A pipelined serve's result as stats and completions."""
         self.stats.requests += len(reqs)
         self.stats.rounds += len(run.groups)
-        self.stats.slo = run.slo()
         self.stats.prefill_tokens += run.prefill_tokens
         self.stats.decode_tokens += run.decode_tokens
         # wall windows (they overlap under pipelining): prefill counts

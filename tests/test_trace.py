@@ -418,3 +418,202 @@ def test_overhead_untraced_path_identical_results():
     assert traced.cycles == plain.cycles
     assert all(f.tracer is None for f in plain.channels.fifos.values())
     assert not plain.wait_cycles and traced.wait_cycles
+
+
+# ===========================================================================
+# profiler spans: the serving path's own spans in the profiler's trace
+# ===========================================================================
+@pytest.fixture(scope="module")
+def serve_setup():
+    import numpy as np
+
+    from repro.configs.base import ShapeCfg
+    from repro.configs.tiny import CONFIG as tiny
+    from repro.core import planner
+    from repro.graphs import lm_graph
+    from repro.runtime.pipeline import DecodePipeline
+    from repro.runtime.server import Request
+
+    shape = ShapeCfg("decode_test", 64, 16, "decode")
+    plan = planner.plan(tiny, shape, chips=8, max_tp=4)
+    stg, _ = lm_graph.build_stg(tiny, shape, max_tp=4)
+    pipe = DecodePipeline(tiny, stg, plan)
+
+    def reqs(n, max_new):
+        rng = np.random.default_rng(n)
+        return [Request(uid=i, prompt=rng.integers(
+                    2, tiny.vocab, rng.integers(4, 20)).tolist(),
+                    max_new=max_new) for i in range(n)]
+    return tiny, pipe, reqs
+
+
+def _host_spans(log_dir):
+    """(name, start_ns, dur_ns, stats) of every program span in the one
+    xplane under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.runtime.pipeline.trace import SPAN_NAMES, SPAN_PREFIXES
+    files = glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True)
+    assert len(files) == 1
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name == "/host:CPU":
+            out.extend((ev.name, ev.start_ns, ev.duration_ns, dict(ev.stats))
+                       for line in plane.lines for ev in line.events
+                       if ev.name in SPAN_NAMES
+                       or ev.name.startswith(SPAN_PREFIXES))
+    return out
+
+
+def test_span_is_shared_null_without_profiler(serve_setup, monkeypatch):
+    """No profiler active: `span` hands back one shared null context that
+    takes metadata and does nothing, and a whole pipelined serve builds
+    no `TraceAnnotation` at all."""
+    from repro.runtime.pipeline import trace as trace_mod
+    from repro.runtime.server import LMServer
+
+    tiny, pipe, reqs = serve_setup
+    assert trace_mod.span("a") is trace_mod.span("b", k=1) \
+        is trace_mod.NULL_SPAN
+    with trace_mod.span("a") as sp:
+        sp.set_metadata(k=1)
+    built = []
+
+    class Counting(trace_mod.TraceAnnotation):
+        def __init__(self, *args, **kw):
+            built.append(args)
+            super().__init__(*args, **kw)
+    monkeypatch.setattr(trace_mod, "TraceAnnotation", Counting)
+    out = LMServer(tiny, max_batch=4, pipeline=pipe).serve(reqs(8, 4))
+    assert len(out) == 8 and all(len(c.tokens) == 4 for c in out)
+    assert built == []
+
+
+def test_profiled_serve_writes_every_span(serve_setup, tmp_path):
+    """Under `jax.profiler.trace` a serve writes every span of the table,
+    a stage span per program, compile spans for the shapes it warms, and
+    the counters: the end mark's slots and real tokens are what
+    ``server.slot_waste_share`` computes from the groups, the sweeps'
+    ``dispatched`` add up to the ops the engine ran.  The `Tracer`'s
+    events, placed on the profiler's clock from the start mark, lie
+    between the engine's two marks."""
+    import jax
+
+    from repro.runtime.pipeline.trace import (SPAN_ENGINE_END,
+                                              SPAN_ENGINE_START, SPAN_NAMES)
+    from repro.runtime.server import LMServer
+
+    tiny, pipe, reqs = serve_setup
+    tr = Tracer()
+    srv = LMServer(tiny, max_batch=3, pipeline=pipe, tracer=tr)
+    batch = reqs(6, 5)
+    with jax.profiler.trace(str(tmp_path)):
+        out = srv.serve(batch)
+    spans = _host_spans(tmp_path)
+    names = {n for n, *_ in spans}
+    assert set(SPAN_NAMES) <= names
+    programs = (pipe._embed, pipe._block_prefill, pipe._block_decode,
+                pipe._head)
+    assert {p.stage_span for p in programs} <= names
+    assert {f"compile.{p.name}" for p in programs} <= names
+
+    run = srv.last_run
+    (end,) = [st for n, _, _, st in spans if n == SPAN_ENGINE_END]
+    assert end["slots"] == sum(g.batch * g.bucket + g.batch * g.steps
+                               for g in run.groups)
+    assert end["real_tokens"] == sum(
+        len(r.prompt) + max(len(c.tokens) - 1, 0)
+        for r, c in zip(batch, out))
+    assert end["late_compiles"] == 0
+    assert sum(st.get("dispatched", 0) for n, _, _, st in spans
+               if n == "engine.sweep") == sum(run.stage_firings.values())
+    stage = [st for n, _, _, st in spans if n.startswith("stage.")]
+    assert len(stage) == sum(run.stage_firings.values())
+    assert {st["kind"] for st in stage} == {"P", "D"}
+    assert {st["stage"] for st in stage} == set(range(len(pipe.stage_names)))
+    heads = [st for n, _, _, st in spans if n == "head.sample"]
+    assert len(heads) == sum(1 + g.steps for g in run.groups)
+    assert {st["batch"] for st in heads} == {3}
+
+    (t_start,) = [s for n, s, _, _ in spans if n == SPAN_ENGINE_START]
+    (t_end,) = [s for n, s, _, _ in spans if n == SPAN_ENGINE_END]
+    assert t_end - t_start == pytest.approx(run.wall_s * 1e9, rel=0.05)
+    events = tr.to_profiler_ns(t_start)
+    assert events
+    slack = 1e6                      # the marks are read just after t0/wall
+    assert all(t_start <= s and s + d <= t_end + slack
+               for _, _, s, d in events)
+    assert set(run.slo()) >= {"ttft_p50_ms", "token_gap_p95_ms"}
+
+
+def test_stage_programs_carry_their_names(serve_setup):
+    """Each stage program compiles as a module named after its program,
+    so a device trace tells them apart."""
+    _tiny, pipe, _reqs = serve_setup
+    for prog in (pipe._embed, pipe._block_prefill, pipe._block_decode,
+                 pipe._head):
+        texts = prog.compiled_texts()
+        assert texts and all(t.startswith(f"HloModule jit_{prog.name},")
+                             for t in texts)
+
+
+def test_tracer_to_profiler_ns():
+    """Op, wait and failover spans move onto the profiler's clock from the
+    start mark; fifo events are not spans; a virtual trace has no
+    profiler time."""
+    tr = Tracer()
+    tr.bind_wall(0.0)
+    tr.op_retire("blocks00", 0, "D", 7, 0, 0.5, 0.75)
+    tr.wait("head", "starve", "act3", 1.0, 1.25)
+    tr.failover("blocks00", 1, "crash", 2.0, 2.5, 3)
+    tr.fifo_event(EV_PUSH, "act0", 1)
+    got = tr.to_profiler_ns(1e9)
+    assert got == [("blocks00/r0", "D7", 1.5e9, 0.25e9),
+                   ("head", "starve", 2.0e9, 0.25e9),
+                   ("blocks00/r1", "failover (crash)", 3.0e9, 0.5e9)]
+    virt = _traced_virtual(fill_drain(2, 2))
+    with pytest.raises(ValueError):
+        virt.to_profiler_ns(0)
+
+
+def test_engine_start_end_hooks():
+    """The generic engine calls its start hook as it reads ``t0`` and its
+    end hook once ``wall_s`` is set."""
+    calls = []
+
+    class One:
+        name, n_replicas = "one", 1
+
+        def __init__(self):
+            self.left = 2
+
+        def pending(self):
+            return self.left
+
+        def peek(self):
+            return Op(stage=0, kind="F", seq=2 - self.left, rep=0) \
+                if self.left else None
+
+        def ready(self, op, count_stall=False):
+            return 0.0
+
+        def dispatch(self, op, driver):
+            self.left -= 1
+            return (lambda: "done"), ()
+
+        def retire(self, op, result, driver):
+            assert result == "done"
+            return driver.t0
+
+        def describe(self):
+            return "one"
+
+    eng = Engine([One()], overlap=False,
+                 on_start=lambda e: calls.append(("start", e.t0)),
+                 on_end=lambda e: calls.append(("end", e.result.wall_s)))
+    res = eng.run()
+    assert [c[0] for c in calls] == ["start", "end"]
+    assert calls[0][1] == eng.t0 and calls[1][1] == res.wall_s
+    assert res.stage_firings["one"] == 2
