@@ -412,10 +412,11 @@ class _ServeStageProgram:
         if kind == "P":
             return (_run_stage_static_cap,
                     (pre, params, payload, g.cap, dev, at))
+        # the position stays a host scalar: the op body puts it on the
+        # op's device, so the engine thread launches no convert per op
         cache = self.caches[gid]
         return (_run_stage,
-                (dec, params,
-                 (cache, payload, jnp.asarray(pos, jnp.int32)), dev, at))
+                (dec, params, (cache, payload, np.int32(pos)), dev, at))
 
     def dispatch(self, op: Op, driver):
         s, S, run = self.s, self.S, self.run
@@ -591,7 +592,8 @@ class _ServeRun:
         self.programs = [_ServeStageProgram(s, pipe, self)
                          for s in range(len(pipe.stage_names))]
         S = len(self.programs)
-        self.acts = [pipe._edge_fifo(s, capacity_blocks, overlap)
+        self.acts = [pipe._edge_fifo(s, capacity_blocks, overlap,
+                                     self.programs[s + 1].rep_of)
                      for s in range(S - 1)]
         # the continuous token stream: head -> embed feedback.  At most
         # one token per live group is ever in flight (a group's next op
@@ -947,17 +949,23 @@ class DecodePipeline:
         return jax.random.categorical(
             sub, logits[:, -1, :] / t, axis=-1).astype(jnp.int32)
 
-    def _edge_fifo(self, s: int, capacity_blocks: int, overlap: bool) -> Fifo:
-        # same slot accounting as the LM pipeline: reservations from
-        # producer dispatch to consumer retirement, plus buffered slack
+    def _edge_fifo(self, s: int, capacity_blocks: int, overlap: bool,
+                   rep_of=None) -> Fifo:
+        """The act edge ``s -> s + 1``.  Same slot accounting as the LM
+        pipeline: reservations from producer dispatch to consumer
+        retirement, plus buffered slack.  With ``overlap`` a queued token
+        ``(seq, (gid, y))`` is staged early: ``y`` alone goes to the
+        device of the consumer replica serving ``gid`` (``rep_of``, the
+        consumer program's routing, so it follows failover), and ``seq``
+        and ``gid`` stay host ints for the consumer's order check."""
         prod = len(self.stage_devices[s])
         cons = len(self.stage_devices[s + 1])
         cons_devs = self.stage_devices[s + 1]
 
         def staging(tok):
-            gid, y = tok
+            seq, (gid, y) = tok
             check_not_donated(y, f"act edge {s}->{s + 1} (gid={gid})")
-            return (gid, jax.device_put(y, cons_devs[gid % cons]))
+            return (seq, (gid, jax.device_put(y, cons_devs[rep_of(gid)])))
 
         slots = (prod + cons) * self.replica_queue
         return Fifo(block=1, capacity_blocks=capacity_blocks,

@@ -12,6 +12,7 @@ Acceptance contract:
   * the graph-generic engine drives dynamically-growing op queues to
     quiescence and frees channel credits when an op's body raises.
 """
+import json
 import os
 import subprocess
 import sys
@@ -357,3 +358,175 @@ def test_multidevice_decode_parity():
                        cwd=os.path.dirname(os.path.dirname(__file__)))
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
     assert "DECODE_PARITY_OK" in r.stdout
+
+
+# ===========================================================================
+# act-edge staging: only the activation goes to the device; a token's
+# group id and a decode op's position stay host scalars
+# ===========================================================================
+_STAGING_MULTIDEV = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import sys
+    sys.path.insert(0, "src")
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.configs.base import ShapeCfg
+    from repro.configs.tiny import CONFIG as tiny
+    from repro.core import planner
+    from repro.graphs import lm_graph
+    from repro.runtime.pipeline import DecodePipeline, as_selection
+    from repro.runtime.pipeline.decode import _ServeRun
+
+    shape = ShapeCfg("staging", 64, 16, "decode")
+    plan = planner.plan(tiny, shape, chips=8, max_tp=4)
+    stg, _ = lm_graph.build_stg(tiny, shape, max_tp=4)
+    sel = as_selection(plan)
+    # two replicas on blocks00, so edge 0 (embed -> blocks00) has a
+    # consumer device to choose by group
+    L = len(tiny.block_pattern)
+    for n in stg.topo_order():
+        if n.startswith("block") and int(n[5:]) < L:
+            sel.set(n, sel.choices[n][0], 2)
+    pipe = DecodePipeline(tiny, stg, sel, warmup=False)
+    cons_devs = pipe.stage_devices[1]
+    assert len(set(cons_devs)) == 2, cons_devs
+
+    def staged(seq, gid, y, rep_map=None):
+        run = _ServeRun(pipe, [], eos_id=1, capacity_blocks=2,
+                        overlap=True)
+        run.programs[1].rep_map.update(rep_map or {})
+        fifo = run.acts[0]
+        fifo.push([(seq, (gid, y))], 0.0)
+        assert fifo.stats.prefetches == 1
+        return fifo.pop_hold(1)[0]
+
+    def y_on_producer():
+        return jax.device_put(jnp.ones((4, 1, tiny.d_model)),
+                              pipe.stage_devices[0][0])
+
+    def gid_is_host_int():
+        seq_got, (gid_got, _) = staged(0, 1, y_on_producer())
+        assert type(seq_got) is int and seq_got == 0, seq_got
+        assert type(gid_got) is int and gid_got == 1, gid_got
+
+    def y_on_gid_replica():
+        # seq 0 and group 1 pick different replicas of the consumer
+        y = y_on_producer()
+        _, (gid_got, y_got) = staged(0, 1, y)
+        assert not isinstance(gid_got, jax.Array), gid_got
+        assert y_got.devices() == {cons_devs[1]}, y_got.devices()
+        assert (np.asarray(y_got) == np.asarray(y)).all()
+
+    def y_follows_rep_map():
+        # failover moved group 1 to replica 0: staging reads the
+        # consumer program's routing, not the affinity rule
+        _, (_, y_got) = staged(1, 1, y_on_producer(), rep_map={1: 0})
+        assert y_got.devices() == {cons_devs[0]}, y_got.devices()
+
+    def donated_y_raises():
+        y = y_on_producer()
+        y.delete()
+        try:
+            staged(0, 1, y)
+        except RuntimeError as e:
+            assert "prefetch on act edge 0->1 (gid=1)" in str(e), e
+            assert "deleted (donated)" in str(e), e
+        else:
+            raise AssertionError("a donated activation was staged")
+
+    out = {}
+    for case in (gid_is_host_int, y_on_gid_replica, y_follows_rep_map,
+                 donated_y_raises):
+        try:
+            case()
+            out[case.__name__] = "ok"
+        except Exception as e:
+            out[case.__name__] = f"{type(e).__name__}: {e}"[:500]
+    print("STAGING", json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def staging_cases():
+    r = subprocess.run([sys.executable, "-c", _STAGING_MULTIDEV],
+                       capture_output=True, text=True, timeout=600,
+                       cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
+    line = [l for l in r.stdout.splitlines() if l.startswith("STAGING ")]
+    assert line, r.stdout[-2000:]
+    return json.loads(line[-1][len("STAGING "):])
+
+
+@pytest.mark.parametrize("case", ["gid_is_host_int", "y_on_gid_replica",
+                                  "y_follows_rep_map", "donated_y_raises"])
+def test_act_edge_staging_puts_only_the_activation(staging_cases, case):
+    """An act edge's prefetch stages a queued ``(seq, (gid, y))``: the
+    group id comes back the host int pushed, only ``y`` moves, onto the
+    device of the consumer replica serving the group, and a donated
+    ``y`` raises the donation error naming the edge (8 CPU devices,
+    subprocess)."""
+    assert staging_cases[case] == "ok", staging_cases[case]
+
+
+@pytest.mark.parametrize("field", ["seq", "gid"])
+def test_act_edge_order_check_fires(decode_setup, monkeypatch, field):
+    """A token reaching an act edge's consumer under the wrong
+    ``(seq, gid)`` stops the serve with ``fifo order broke``."""
+    plan, stg = decode_setup
+    pipe = DecodePipeline(tiny, stg, plan)
+    push = Fifo.push_reserved
+    bad = []
+
+    def misorder(self, tokens, ready_time):
+        if not bad:
+            ((seq, (gid, y)),) = tokens
+            tokens = [(seq + 1, (gid, y)) if field == "seq"
+                      else (seq, (gid + 1, y))]
+            bad.append(tokens)
+        return push(self, tokens, ready_time)
+
+    monkeypatch.setattr(Fifo, "push_reserved", misorder)
+    with pytest.raises(AssertionError, match="fifo order broke"):
+        pipe.serve([list(range(2, 12))] * 8, 4, group_size=4)
+    assert bad
+
+
+def test_served_wave_hands_dispatch_host_scalars(decode_setup, monkeypatch):
+    """With overlap on, every token an act edge hands ``dispatch`` carries
+    its group id as a host int, and every decode op's position is a host
+    int32 scalar — no device value where the engine thread compares or
+    converts a scalar — and the tokens are those of the unwrapped
+    serve."""
+    import jax
+    from repro.runtime.pipeline.decode import _ServeStageProgram
+
+    plan, stg = decode_setup
+    pipe = DecodePipeline(tiny, stg, plan, overlap=True)
+    prompts = [list(range(2, 12))] * 8
+    ref = pipe.serve(prompts, 6, group_size=4).tokens
+
+    popped, positions = [], []
+    pop_hold, task_for = Fifo.pop_hold, _ServeStageProgram._task_for
+
+    def recording_pop_hold(self, n=None):
+        out = pop_hold(self, n)
+        popped.extend(out)
+        return out
+
+    def recording_task_for(self, kind, gid, pos, payload, rep):
+        fn, args = task_for(self, kind, gid, pos, payload, rep)
+        if kind == "D" and self.pipe.stage_descs[self.s].span is not None:
+            positions.append(args[2][2])
+        return fn, args
+
+    monkeypatch.setattr(Fifo, "pop_hold", recording_pop_hold)
+    monkeypatch.setattr(_ServeStageProgram, "_task_for", recording_task_for)
+    got = pipe.serve(prompts, 6, group_size=4).tokens
+
+    assert got == ref
+    assert popped and positions
+    for seq, (gid, _y) in popped:
+        assert type(seq) is int and type(gid) is int, (seq, gid)
+    for pos in positions:
+        assert not isinstance(pos, jax.Array), pos
+        assert isinstance(pos, np.int32), type(pos)
