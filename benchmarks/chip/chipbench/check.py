@@ -73,10 +73,14 @@ def _gap_stats(gaps, served) -> dict:
 
 
 def _blocks(ref, params, m: dict, inputs, probes, quant=None):
-    """`ref.gaps` over the rows of ``inputs``, one block at a time."""
+    """The reference's gaps over the rows of ``inputs``, one block at a
+    time: in one pass where each device holds the whole ``layers`` stack,
+    chip by chip where it is split (`refpass`)."""
+    from . import refpass
+    gaps = refpass.by_chip if refpass.is_split(params["layers"]) else refpass.one_pass
     tokens, positions, served = inputs
-    outs = [ref.gaps(params, m, tokens[i:i + BLOCK], positions[i:i + BLOCK],
-                     served[i:i + BLOCK], probes[i:i + BLOCK], quant=quant)
+    outs = [gaps(ref, params, m, tokens[i:i + BLOCK], positions[i:i + BLOCK],
+                 served[i:i + BLOCK], probes[i:i + BLOCK], quant=quant)
             for i in range(0, len(tokens), BLOCK)]
     return tuple(np.concatenate([np.asarray(o[k]) for o in outs]) for k in range(3))
 

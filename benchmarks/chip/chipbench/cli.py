@@ -36,15 +36,19 @@ COMPILE_EVENTS = ("backend_compile_duration", "cache_retrieval_time")
 
 class _Phases:
     """Prints how long each set-up phase took and the devices' memory peak
-    after it."""
+    after it: the largest, and each chip's where there are several."""
 
     def __init__(self, devices):
         self.devices, self.t = devices, time.perf_counter()
 
     def __call__(self, name: str) -> None:
         now = time.perf_counter()
-        peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in self.devices)
-        _say(f"set-up {name}: {now - self.t:.3f} s, memory peak {peak / 1e9:.3f} GB")
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in self.devices]
+        each = ""
+        if len(peaks) > 1:
+            each = " (" + ", ".join(f"{d.id}: {p / 1e9:.3f}"
+                                    for d, p in zip(self.devices, peaks)) + ")"
+        _say(f"set-up {name}: {now - self.t:.3f} s, memory peak {max(peaks) / 1e9:.3f} GB{each}")
         self.t = now
 
 
@@ -68,7 +72,7 @@ class Cell:
         mix = registry.traffic(mix_name)
         ref = registry.reference(m["kind"])
         phase = _Phases(devices)
-        params = weights.make(ref.layout(m), seed, devices[0])
+        params = weights.make(ref.layout(m), seed, devices)
         phase("weights")
         waves = registry.generator(mix["kind"]).generate(mix, seed, m["vocab"], traffic)
         max_prompt = max(traffic.length_deck(mix["prompt"], mix["max_batch"]))

@@ -48,6 +48,19 @@ def rmsnorm(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w.astype(F32)
 
 
+def embed_in(params, tokens, m: dict):
+    """Token ids (B, T) -> float32 rows (B, T, d) of ``params["embed"]``."""
+    return jnp.take(params["embed"], tokens, axis=0).astype(F32)
+
+
+def tied_readout(params, x, positions, served, probes, m: dict, quant=None):
+    """`readout` of the hidden states ``x`` (B, T, d) at ``positions``
+    (B, K), final-normed, against the tied head ``params["embed"]``."""
+    h = jnp.take_along_axis(x, positions[..., None], axis=1)    # (B, K, d)
+    h = rmsnorm(h, params["final_norm"], m["norm_eps"])
+    return readout(h, params["embed"], served, probes, quant)
+
+
 def readout(h, head_rows, served, probes, quant=None):
     """Logits of the normed hidden states ``h`` (B, K, d) against the
     tied head ``head_rows`` (V, d), then per position: how far the

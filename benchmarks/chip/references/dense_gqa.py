@@ -4,15 +4,14 @@ as Qwen2 publishes it (Qwen/Qwen2.5-3B config.json and the Qwen2 model
 description): every sublayer pre-norm and residual, biases on the q/k/v
 projections, rotary embedding over halves of each head.
 
-No cache and no kernels: the whole sequence in one pass, layer by layer.
-The parameter tree is the one the benchmark draws (`layout`); its names
-follow the serving program's so the same arrays can be handed to it.
-The kind's counts for the yardstick (`layer_flops`, `layer_step_bytes`)
-sit here too.
+No cache and no kernels: the whole sequence in one pass, layer by layer,
+in the three pieces `chipbench.refpass` composes (`embed_in`,
+`layer_stack`, `readout`).  The parameter tree is the one the benchmark
+draws (`layout`); its names follow the serving program's so the same
+arrays can be handed to it.  The kind's counts for the yardstick
+(`layer_flops`, `layer_step_bytes`) sit here too.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -105,22 +104,14 @@ def _layer(m, quant, x, p):
     return x + mm(g, f["w_down"], quant)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "quant"))
-def _gaps(params, tokens, positions, served, probes, *, m, quant):
-    m = dict(m)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(F32)
+# the embedding and the read-out are the tied head's, shared by the kinds
+embed_in = refmath.embed_in
+readout = refmath.tied_readout
 
+
+def layer_stack(layers, x, m: dict, quant=None):
+    """Every period of the stacked ``layers`` over ``x`` (B, T, d), in order."""
     def body(h, p):
         return _layer(m, quant, h, p["pos0"]), None
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    h = jnp.take_along_axis(x, positions[..., None], axis=1)    # (B, K, d)
-    h = rmsnorm(h, params["final_norm"], m["norm_eps"])
-    return refmath.readout(h, params["embed"], served, probes, quant)
-
-
-def gaps(params, m: dict, tokens, positions, served, probes, quant=None):
-    """Per read position (B, K): the served token's and the probe token's
-    distance below the best logit, and the best token, for the sequences
-    ``tokens`` (B, T) read at ``positions`` (B, K)."""
-    return _gaps(params, tokens, positions, served, probes,
-                 m=tuple(sorted(m.items())), quant=quant)
+    x, _ = jax.lax.scan(body, x, layers)
+    return x

@@ -3,7 +3,9 @@
 group of B/C shared by all heads, a depthwise causal convolution, the
 gated RMSNorm (norm of y * silu(z)) and a tied head.
 
-The state recurrence is run token by token, the plain form of SSD:
+The model is given in the three pieces `chipbench.refpass` composes
+(`embed_in`, `layer_stack`, `readout`).  The state recurrence is run
+token by token, the plain form of SSD:
     s_t = exp(dt_t * A) s_{t-1} + dt_t * x_t B_t^T,   y_t = s_t C_t + D x_t
 with A = -exp(a_log) and dt_t = softplus(dt_raw_t + dt_bias).
 
@@ -14,8 +16,6 @@ and C together, with a bias).  The kind's counts for the yardstick
 (`layer_flops`, `layer_step_bytes`) sit here too.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -112,20 +112,14 @@ def _layer(m, quant, x, p):
     return x + mm(y, a["w_out"], quant)
 
 
-@functools.partial(jax.jit, static_argnames=("m", "quant"))
-def _gaps(params, tokens, positions, served, probes, *, m, quant):
-    m = dict(m)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(F32)
+# the embedding and the read-out are the tied head's, shared by the kinds
+embed_in = refmath.embed_in
+readout = refmath.tied_readout
 
+
+def layer_stack(layers, x, m: dict, quant=None):
+    """Every period of the stacked ``layers`` over ``x`` (B, T, d), in order."""
     def body(h, p):
         return _layer(m, quant, h, p["pos0"]), None
-    x, _ = jax.lax.scan(body, x, params["layers"])
-    h = jnp.take_along_axis(x, positions[..., None], axis=1)
-    h = rmsnorm(h, params["final_norm"], m["norm_eps"])
-    return refmath.readout(h, params["embed"], served, probes, quant)
-
-
-def gaps(params, m: dict, tokens, positions, served, probes, quant=None):
-    """As `dense_gqa.gaps`, for this model."""
-    return _gaps(params, tokens, positions, served, probes,
-                 m=tuple(sorted(m.items())), quant=quant)
+    x, _ = jax.lax.scan(body, x, layers)
+    return x

@@ -26,6 +26,7 @@ TEST_CELLS = [
     {"name": "tiny-dense.lognormal", "config": "tiny-dense", "traffic": "tiny-lognormal", "chips": 1},
     {"name": "tiny-dense.long", "config": "tiny-dense", "traffic": "tiny-long", "chips": 1},
     {"name": "tiny-mamba.long", "config": "tiny-mamba", "traffic": "tiny-long", "chips": 1},
+    {"name": "tiny-dense-4l.chat", "config": "tiny-dense-4l", "traffic": "tiny-chat", "chips": 4},
 ]
 
 
